@@ -29,6 +29,8 @@ import time
 import traceback
 from typing import List
 
+from repro import device
+
 
 def _rows_to_json(rows: List[str]) -> List[dict]:
     out = []
@@ -89,6 +91,8 @@ def main() -> None:
         start = len(csv)
         payload = {"module": name, "ok": True}
         try:
+            if name != "analysis":  # the static-analysis gate runs without jax
+                device.use_compile_cache()
             mod = importlib.import_module(f"benchmarks.{name}")
             mod.run(csv)
             print(f"# {name}: ok ({time.time()-t0:.1f}s)", file=sys.stderr)

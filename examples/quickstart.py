@@ -9,6 +9,7 @@ scheduling), and prints parameter importance plus the reuse accounting.
 
 import numpy as np
 
+from repro import device
 from repro.app import run_study, synthetic_tile
 from repro.core import ParamSpace, moat_indices, morris_trajectories
 
@@ -25,6 +26,7 @@ SPACE = ParamSpace.from_dict(
 
 
 def main() -> None:
+    device.use_compile_cache()
     tile = synthetic_tile(96, 96, seed=7)
     sets, moves = morris_trajectories(SPACE, 3, seed=0)
     print(f"MOAT study: {len(sets)} runs over {SPACE.dim} parameters")

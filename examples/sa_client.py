@@ -17,11 +17,13 @@ from __future__ import annotations
 
 import threading
 
+from repro import device
 from repro.app.pipeline import pathology_service_build
 from repro.service import ServiceClient, StudyServer, StudySpec
 
 
 def main() -> None:
+    device.use_compile_cache()
     server = StudyServer.from_build(
         pathology_service_build,
         {"size": 32, "n_tiles": 2, "seed": 0},

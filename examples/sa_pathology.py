@@ -54,6 +54,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro import device
 from repro.app import synthetic_tile
 from repro.app.pipeline import build_workflow, TABLE1_SPACE
 from repro.core import correlation_indices, dice, morris_trajectories
@@ -148,6 +149,7 @@ def run_fleet(args) -> None:
 
 
 def main() -> None:
+    device.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--runs", type=int, default=48)
     ap.add_argument("--tiles", type=int, default=4)

@@ -31,6 +31,7 @@ frame, so the only coordination needed is the address (and a matching
 
 import argparse
 
+from repro import device
 from repro.app import synthetic_tile
 from repro.app.pipeline import pathology_rpc_build
 from repro.runtime.net import run_worker
@@ -44,6 +45,7 @@ def pathology_worker_build(n_tiles: int = 4, size: int = 72):
 
 
 def main() -> int:
+    device.use_compile_cache()
     ap = argparse.ArgumentParser(
         description="Join a pathology SA socket fleet (DESIGN.md §16)"
     )

@@ -14,12 +14,14 @@ import itertools
 import jax
 import numpy as np
 
+from repro import device
 from repro.configs import get_config, reduced_config
 from repro.core.sa_serve import run_sa_serve
 from repro.models import init_params
 
 
 def main() -> None:
+    device.use_compile_cache()
     cfg = reduced_config(get_config("gemma3_1b"))
     params = init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(0)

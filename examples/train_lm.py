@@ -16,6 +16,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import device
 from repro.checkpoint import Checkpointer
 from repro.configs import SHAPES, get_config, reduced_config
 from repro.data import TokenPipeline
@@ -25,6 +26,7 @@ from repro.optim import OptConfig, adamw_init
 
 
 def main() -> None:
+    device.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi_6b")
     ap.add_argument("--steps", type=int, default=30)

@@ -6,7 +6,8 @@ each run's mask with the default-parameter mask (Dice). Every operator below
 is a pure, jittable function on ``float32``/``bool`` arrays; the propagation
 hot-spot (morphological reconstruction, also the engine behind fill-holes and
 the watershed flooding) has a Pallas TPU kernel in
-``repro.kernels.morph_recon`` — here we call its dispatching wrapper.
+``repro.kernels.morph_recon``; the pipeline runs the same XLA loop on every
+backend and calls the kernel only when asked to by name.
 
 Connectivity parameters (FH / RC / WConn in Table I) are 4 or 8 and must be
 *static* under jit (they select the structuring element).
@@ -69,11 +70,12 @@ def rbc_mask(rgb: jax.Array, t1: jax.Array, t2: jax.Array) -> jax.Array:
 
 
 def morph_reconstruct(
-    marker: jax.Array, mask: jax.Array, conn: int = 8, *, use_kernel: bool = True
+    marker: jax.Array, mask: jax.Array, conn: int = 8, *, use_kernel: bool = False
 ) -> jax.Array:
     """Grayscale morphological reconstruction by dilation: iterate
-    ``marker ← min(dilate(marker), mask)`` to fixpoint. Dispatches to the
-    Pallas tile kernel on TPU; pure-XLA loop elsewhere."""
+    ``marker ← min(dilate(marker), mask)`` to fixpoint. The pure-XLA loop
+    on every backend; ``use_kernel=True`` asks for the Pallas tile kernel,
+    which needs a TPU."""
     from repro.kernels import ops as kops
 
     return kops.morph_reconstruct(marker, mask, conn=conn, use_kernel=use_kernel)

@@ -34,20 +34,7 @@ __all__ = [
     "cache_shardings",
     "constrain_qkv",
     "constrain_hidden",
-    "shard_map_compat",
 ]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, across jax versions
-    (jax < 0.5 only ships jax.experimental.shard_map with `check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
 
 
 @dataclasses.dataclass(frozen=True)

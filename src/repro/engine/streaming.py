@@ -264,6 +264,9 @@ def execute_study(
                     # dispatch: input first (stage s+1 chases stage s's
                     # worker), then the bucket's trie scope
                     path=(f"{key_prefix}{input_keys[i]}",) + bucket.cache_scope,
+                    # stages differ in cost by orders of magnitude, and each
+                    # one's first bucket compiles: judge stragglers per stage
+                    kind=stage_plan.stage.name,
                     callback=lambda _key, value, i=i, si=si: on_bucket(i, si, value),
                     shared=shared,
                     tenant=tenant,

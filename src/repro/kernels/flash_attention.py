@@ -92,7 +92,7 @@ def flash_attention_pallas(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape

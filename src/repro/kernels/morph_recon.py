@@ -73,7 +73,7 @@ def tile_sweep(
     conn: int = 8,
     block: Tuple[int, int] = (256, 256),
     inner_iters: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """One kernel launch: every block independently runs ``inner_iters``
     local reconstruction sweeps. Pads to block multiples with -inf marker /
@@ -109,7 +109,7 @@ def morph_reconstruct_pallas(
     conn: int = 8,
     block: Tuple[int, int] = (256, 256),
     inner_iters: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Full reconstruction to the global fixpoint (kernel sweeps + cross-tile
     exchange). Matches ``ref.morph_reconstruct_ref`` exactly."""

@@ -1,27 +1,33 @@
 """Jitted dispatching wrappers for the Pallas kernels.
 
-Every wrapper picks the Pallas path on TPU backends and the pure-XLA
-reference path elsewhere (this CPU container validates kernels via
-``interpret=True`` in the tests; production runs lower the real kernels).
-The choice is overridable per call for testing/benchmarking.
+With ``use_kernel=None`` a wrapper runs the Pallas kernel on a TPU backend
+and the pure-XLA reference path elsewhere. ``use_kernel=True`` asks for the
+kernel, which is an error off a TPU: the Pallas interpreter runs only where
+a caller of the kernel itself asks for it by name (``interpret=True``, as
+the kernel tests do).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
+from repro import device
 from repro.kernels import ref as kref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def _want_kernel(use_kernel: Optional[bool], name: str) -> bool:
+    on_tpu = device.on_tpu()
+    if use_kernel is None:
+        return on_tpu
+    if use_kernel and not on_tpu:
+        raise RuntimeError(
+            f"the {name} Pallas kernel was asked for off a TPU "
+            f"(backend {jax.default_backend()!r}); use_kernel=False runs the "
+            "XLA reference"
+        )
+    return use_kernel
 
 
 def morph_reconstruct(
@@ -34,8 +40,7 @@ def morph_reconstruct(
     inner_iters: int = 8,
 ) -> jax.Array:
     """Morphological reconstruction by dilation (see kernels/morph_recon.py)."""
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
-    if use_kernel:
+    if _want_kernel(use_kernel, "morph_reconstruct"):
         from repro.kernels.morph_recon import morph_reconstruct_pallas
 
         return morph_reconstruct_pallas(
@@ -44,7 +49,6 @@ def morph_reconstruct(
             conn=conn,
             block=block,
             inner_iters=inner_iters,
-            interpret=not _on_tpu(),
         )
     return kref.morph_reconstruct_ref(marker, mask, conn=conn)
 
@@ -61,8 +65,7 @@ def flash_attention(
     block_k: int = 128,
 ) -> jax.Array:
     """Blocked FlashAttention-2 (see kernels/flash_attention.py)."""
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
-    if use_kernel:
+    if _want_kernel(use_kernel, "flash_attention"):
         from repro.kernels.flash_attention import flash_attention_pallas
 
         return flash_attention_pallas(
@@ -73,7 +76,6 @@ def flash_attention(
             window=window,
             block_q=block_q,
             block_k=block_k,
-            interpret=not _on_tpu(),
         )
     return kref.attention_ref(q, k, v, causal=causal, window=window)
 
@@ -95,9 +97,8 @@ def ssm_scan(
     through the sequential chunk loop)."""
     if analysis:
         return kref.ssm_scan_stub(x, a, b, c, h0)
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
-    if use_kernel:
+    if _want_kernel(use_kernel, "ssm_scan"):
         from repro.kernels.ssm_scan import ssm_scan_pallas
 
-        return ssm_scan_pallas(x, a, b, c, h0, chunk=chunk, interpret=not _on_tpu())
+        return ssm_scan_pallas(x, a, b, c, h0, chunk=chunk)
     return kref.ssm_scan_xla(x, a, b, c, h0, chunk=chunk)

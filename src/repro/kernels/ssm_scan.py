@@ -74,7 +74,7 @@ def ssm_scan_pallas(
     h0: Optional[jax.Array] = None,    # must be None/zeros (kernel owns state)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     bsz, s, h, p = x.shape
     n = b.shape[-1]

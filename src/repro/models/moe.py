@@ -134,11 +134,10 @@ def moe_ffn(
         )
         return y.reshape(bl, sl, d)
 
-    from repro.dist.sharding import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(xspec,) + wspec,
         out_specs=xspec,
+        check_vma=False,
     )(x, rw, wg, wu, wd)

@@ -65,13 +65,12 @@ def make_dp_grad_reducer(mesh, dp_axes: Tuple[str, ...], scheme: str = "bf16"):
                 out = compressed_psum(out, a, scheme)
             return out / n
 
-        from repro.dist.sharding import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=P(*([None] * g.ndim)),
             out_specs=P(*([None] * g.ndim)),
+            check_vma=False,
         )(g)
 
     return lambda grads: jax.tree.map(_reduce_leaf, grads)

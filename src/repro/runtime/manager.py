@@ -7,12 +7,13 @@ needs:
 * heartbeats + retry — a bucket whose Worker misses its heartbeat deadline
   is re-enqueued (at-least-once; results are idempotent because tasks are
   pure functions of (input, params)); the deadline adapts to observed
-  bucket times so a long-running bucket (e.g. a first-time jit compile) is
-  not mistaken for a dead Worker, and a lease whose Worker is *provably*
-  dead (a killed worker process) is re-enqueued immediately;
+  bucket times of the item's own kind (``WorkItem.kind``, the stage) so a
+  long-running bucket (e.g. a first-time jit compile) is not mistaken for
+  a dead Worker, and a lease whose Worker is *provably* dead (a killed
+  worker process) is re-enqueued immediately;
 * straggler mitigation — when the queue is empty and a bucket has been
-  running longer than ``straggler_factor`` × the median bucket time, a
-  backup copy is launched on an idle Worker; first completion wins (the
+  running longer than ``straggler_factor`` × the median time of its kind,
+  a backup copy is launched on an idle Worker; first completion wins (the
   classic demand-driven tail-cloning trick);
 * elastic scaling — Workers can join/leave between buckets; the Manager
   only tracks outstanding leases.
@@ -140,6 +141,22 @@ class WorkItem:
     # immediately. Requires keys derived from task CONTENT, so identical
     # keys always denote identical pure work.
     shared: bool = False
+    # Cost class for the heartbeat-expiry and straggler heuristics: a lease
+    # is judged only against completed durations of its own kind, and a
+    # kind with no completion yet is never age-expired or cloned — its
+    # first bucket may be a multi-minute jit compile. The streaming
+    # executor passes the stage name, so a cheap stage's median cannot
+    # condemn a costly stage's bucket.
+    kind: str = ""
+
+    def next_attempt(self) -> "WorkItem":
+        """A queue entry for another attempt of this item (retry, expiry
+        re-enqueue or backup clone): same work, routing and class, no lease
+        state and no callback (callbacks live in the Manager's table)."""
+        return WorkItem(key=self.key, fn=self.fn, spec=self.spec,
+                        attempt_base=self.attempt_base, path=self.path,
+                        tenant=self.tenant, priority=self.priority,
+                        kind=self.kind)
 
 
 class _SubPump:
@@ -235,12 +252,12 @@ class Manager:
         # while the old lifecycle's attempt still ran): their completions
         # must not settle the new lifecycle, so they are dropped on arrival.
         self._orphaned: set = set()  # guard: _lock
-        # Recent-window of winning-attempt durations for the straggler /
-        # heartbeat heuristics: bounded so a session spanning thousands of
-        # inputs never grows the median computation, with the sorted median
-        # cached between appends (the pump polls it every tick).
-        self._durations: "collections.deque[float]" = collections.deque(maxlen=512)  # guard: _lock
-        self._median_cache: Optional[float] = None  # guard: _lock
+        # Per-kind recent windows of winning-attempt durations for the
+        # straggler / heartbeat heuristics: bounded so a session spanning
+        # thousands of inputs never grows the median computation, with each
+        # sorted median cached between appends (the pump polls every tick).
+        self._durations: Dict[str, "collections.deque[float]"] = {}  # guard: _lock
+        self._median_cache: Dict[str, float] = {}  # guard: _lock
         self._busy_total = 0.0  # guard: _lock — lifetime sum (the efficiency numerator)
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -354,18 +371,23 @@ class Manager:
             }
         return stats
 
-    def _record_duration_locked(self, dur: float) -> None:
-        self._durations.append(dur)
-        self._busy_total += dur
-        self._median_cache = None
+    def _record_duration_locked(self, kind: str, dur: float) -> None:
+        window = self._durations.get(kind)
+        if window is None:
+            window = self._durations[kind] = collections.deque(maxlen=512)
+        window.append(dur)
+        self._median_cache.pop(kind, None)
 
-    def _median_locked(self) -> Optional[float]:
-        if not self._durations:
+    def _median_locked(self, kind: str) -> Optional[float]:
+        """Median completed duration of ``kind``; None before its first
+        completion."""
+        window = self._durations.get(kind)
+        if not window:
             return None
-        if self._median_cache is None:
-            ordered = sorted(self._durations)
-            self._median_cache = ordered[len(ordered) // 2]
-        return self._median_cache
+        if kind not in self._median_cache:
+            ordered = sorted(window)
+            self._median_cache[kind] = ordered[len(ordered) // 2]
+        return self._median_cache[kind]
 
     # ------------------------------------------------------------------
     # Session lifecycle
@@ -873,12 +895,7 @@ class Manager:
                     < self.max_attempts
                 ):
                     self.retries += 1
-                    self._queue.append(
-                        WorkItem(key=item.key, fn=item.fn, spec=item.spec,
-                                 attempt_base=item.attempt_base,
-                                 path=item.path, tenant=item.tenant,
-                                 priority=item.priority)
-                    )
+                    self._queue.append(item.next_attempt())
                     self._cond.notify_all()
                 elif not any(
                     it.key == item.key for it in self._running.values()
@@ -903,10 +920,12 @@ class Manager:
 
         In-process Workers cannot prove liveness while inside a task fn, so
         a long bucket is indistinguishable from a dead Worker by age alone.
-        The deadline therefore adapts to observed bucket times — ``max(
-        heartbeat_timeout, straggler_factor × median)`` — and with no
-        completed-bucket history yet (e.g. the first bucket is a multi-
-        minute jit compile) nothing is ever expired.
+        The deadline therefore adapts to observed bucket times of the
+        lease's own kind — ``max(heartbeat_timeout, straggler_factor ×
+        median(kind))`` — and a lease whose kind has no completed history
+        yet (e.g. the first segmentation bucket is a multi-minute jit
+        compile, while cheap normalization buckets have already finished)
+        is never expired.
 
         ``view`` is passed only by backends whose heartbeats PROVE liveness
         mid-task (the RPC backend's workers sign life from a side thread):
@@ -916,10 +935,6 @@ class Manager:
         wedged worker whose heartbeats stop re-enters age-based expiry.
         (Provably-dead workers are handled separately and immediately by
         ``_expire_dead_locked``.)"""
-        median = self._median_locked()
-        if median is None:
-            return
-        deadline = max(self.heartbeat_timeout, self.straggler_factor * median)
         now = time.monotonic()
         proven_live: set = set()
         if view is not None:
@@ -931,6 +946,10 @@ class Manager:
                 continue
             if lease in proven_live:
                 continue
+            median = self._median_locked(it.kind)
+            if median is None:
+                continue
+            deadline = max(self.heartbeat_timeout, self.straggler_factor * median)
             started = it.started_at or now
             if now - started <= deadline:
                 continue
@@ -942,42 +961,35 @@ class Manager:
             del self._running[lease]
             self.heartbeat_expiries += 1
             self.retries += 1
-            self._queue.append(WorkItem(key=it.key, fn=it.fn, spec=it.spec,
-                                        attempt_base=it.attempt_base,
-                                        path=it.path, tenant=it.tenant,
-                                        priority=it.priority))
+            self._queue.append(it.next_attempt())
             self._cond.notify_all()
 
     def _maybe_backup_locked(self) -> Optional[WorkItem]:
-        """Clone the longest-running bucket if it looks like a straggler.
-        Caller holds ``self._lock``. At most one backup of a key is in
-        flight at a time: while original + clone both run, the key holds two
-        leases and is skipped."""
-        if not self.enable_backup_tasks:
+        """Clone the longest-running bucket that looks like a straggler
+        against its own kind (at least two completions of that kind, and
+        older than ``straggler_factor`` × their median). Caller holds
+        ``self._lock``. At most one backup of a key is in flight at a time:
+        while original + clone both run, the key holds two leases and is
+        skipped."""
+        if not self.enable_backup_tasks or not self._running:
             return None
-        if not self._running or len(self._durations) < 2:
-            return None
-        median = self._median_locked()
         now = time.monotonic()
-        candidates = [
+        stragglers = [
             it
             for it in self._running.values()
             if it.key not in self._results
+            and len(self._durations.get(it.kind, ())) >= 2
+            and now - (it.started_at or now)
+            > self.straggler_factor * max(self._median_locked(it.kind), 1e-3)
             and sum(1 for other in self._running.values() if other.key == it.key) < 2
             and self._attempt_seq.get(it.key, 0) - it.attempt_base
             < self.max_attempts
         ]
-        if not candidates:
+        if not stragglers:
             return None
-        worst = max(candidates, key=lambda it: now - (it.started_at or now))
-        age = now - (worst.started_at or now)
-        if age > self.straggler_factor * max(median, 1e-3):
-            self.backups_launched += 1
-            return WorkItem(key=worst.key, fn=worst.fn, spec=worst.spec,
-                            attempt_base=worst.attempt_base,
-                            path=worst.path, tenant=worst.tenant,
-                            priority=worst.priority)
-        return None
+        worst = max(stragglers, key=lambda it: now - (it.started_at or now))
+        self.backups_launched += 1
+        return worst.next_attempt()
 
     def _sub_pump(self, sub: _SubPump) -> None:
         """Sub-manager pump thread wrapper: a crashed pump returns its
@@ -1153,12 +1165,17 @@ class Manager:
         cbs: Optional[List[Callable[[str, Any], None]]] = None
         won = False
         with self._cond:
-            self._running.pop(f"{key}#{attempt}", None)
+            item = self._running.pop(f"{key}#{attempt}", None)
             if key not in self._results:  # first completion wins
                 won = True
                 self._results[key] = value
                 if duration is not None and not isinstance(value, Exception):
-                    self._record_duration_locked(duration)
+                    self._busy_total += duration
+                    # an attempt whose lease expired (its worker presumed
+                    # dead) has left _running, and with it the kind its
+                    # duration would be filed under
+                    if item is not None:
+                        self._record_duration_locked(item.kind, duration)
                 cbs = self._callbacks.pop(key, None)
             self._drain_deferred_locked(key)
             self._cond.notify_all()
@@ -1213,12 +1230,7 @@ class Manager:
             ):
                 self.retries += 1
                 # attempt numbers are issued by _next_locked at lease time
-                self._queue.append(
-                    WorkItem(key=item.key, fn=item.fn, spec=item.spec,
-                             attempt_base=item.attempt_base,
-                             path=item.path, tenant=item.tenant,
-                             priority=item.priority)
-                )
+                self._queue.append(item.next_attempt())
                 self._cond.notify_all()
                 return
             if item is None and comp.key not in self._results:

@@ -52,6 +52,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro import device
 from repro.runtime.transport import (
     Completion,
     Lease,
@@ -520,6 +521,9 @@ class SocketBackend:
         if self._listener is not None:
             raise RuntimeError("SocketBackend already started")
         import uuid
+
+        if self.spawn_workers:
+            device.refuse_child_processes_on_tpu("SocketBackend(spawn_workers=True)")
 
         n = max(1, n_workers)
         self._session = uuid.uuid4().hex[:12]

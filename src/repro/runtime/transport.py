@@ -80,9 +80,13 @@ import struct
 import threading
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Dict, List, Optional, Protocol, Tuple, runtime_checkable,
+)
 
 import numpy as np
+
+from repro import device
 
 __all__ = [
     "Lease",
@@ -168,60 +172,54 @@ class WorkerStatus:
     inflight: Tuple[str, ...] = ()
 
 
-try:  # Protocol is typing-only; keep the module importable everywhere
-    from typing import Protocol, runtime_checkable
+@runtime_checkable
+class WorkerBackend(Protocol):
+    """The Manager↔Worker contract. Implementations own worker
+    lifecycle and execution; the Manager owns every scheduling
+    decision.
 
-    @runtime_checkable
-    class WorkerBackend(Protocol):
-        """The Manager↔Worker contract. Implementations own worker
-        lifecycle and execution; the Manager owns every scheduling
-        decision.
+    Beyond the five methods, two class flags complete the contract:
+    ``supports_specs`` (True ⇒ leases are shipped by picklable spec,
+    closures never cross — the executor then also requires an
+    ``install_study(**study)`` method to broadcast plan recipes before
+    any bucket lease references them) and
+    ``heartbeats_prove_liveness`` (True ⇒ a fresh ``last_seen`` proves
+    a worker's leases live mid-task, sparing them age-based expiry).
 
-        Beyond the five methods, two class flags complete the contract:
-        ``supports_specs`` (True ⇒ leases are shipped by picklable spec,
-        closures never cross — the executor then also requires an
-        ``install_study(**study)`` method to broadcast plan recipes before
-        any bucket lease references them) and
-        ``heartbeats_prove_liveness`` (True ⇒ a fresh ``last_seen`` proves
-        a worker's leases live mid-task, sparing them age-based expiry).
+    Further methods are optional; the Manager discovers them by
+    ``getattr``: ``offer_batch(leases, worker_ids=None) -> rejected``
+    (batched dispatch; paired with a ``slots_per_worker`` attribute so
+    the pump sizes demand as queue depth, not just free workers;
+    ``worker_ids`` restricts a batch to a shard for the hierarchical
+    scheduler's sub-manager pumps), ``offer_to(lease, worker_id) ->
+    bool`` (locality-targeted single-worker offer, DESIGN.md §15) and
+    ``barrier(timeout=None) -> bool`` (durability point for backends
+    that acknowledge completions ahead of their disk commit;
+    ``Manager.drain`` invokes it when present).
+    """
 
-        Further methods are optional; the Manager discovers them by
-        ``getattr``: ``offer_batch(leases, worker_ids=None) -> rejected``
-        (batched dispatch; paired with a ``slots_per_worker`` attribute so
-        the pump sizes demand as queue depth, not just free workers;
-        ``worker_ids`` restricts a batch to a shard for the hierarchical
-        scheduler's sub-manager pumps), ``offer_to(lease, worker_id) ->
-        bool`` (locality-targeted single-worker offer, DESIGN.md §15) and
-        ``barrier(timeout=None) -> bool`` (durability point for backends
-        that acknowledge completions ahead of their disk commit;
-        ``Manager.drain`` invokes it when present).
-        """
+    name: str
+    supports_specs: bool
+    heartbeats_prove_liveness: bool
 
-        name: str
-        supports_specs: bool
-        heartbeats_prove_liveness: bool
+    def start(self, n_workers: int) -> None:
+        """Bring up the worker pool (idempotent per session; a backend
+        may be restarted after ``shutdown``)."""
 
-        def start(self, n_workers: int) -> None:
-            """Bring up the worker pool (idempotent per session; a backend
-            may be restarted after ``shutdown``)."""
+    def offer(self, lease: Lease) -> bool:
+        """Hand a lease to a free worker. Returns False when no worker
+        can take it right now (the Manager re-queues the item)."""
 
-        def offer(self, lease: Lease) -> bool:
-            """Hand a lease to a free worker. Returns False when no worker
-            can take it right now (the Manager re-queues the item)."""
+    def poll_completions(self, timeout: float) -> List["Completion"]:
+        """Block up to ``timeout`` seconds for completions; drain and
+        return everything available (possibly empty)."""
 
-        def poll_completions(self, timeout: float) -> List["Completion"]:
-            """Block up to ``timeout`` seconds for completions; drain and
-            return everything available (possibly empty)."""
+    def heartbeat_view(self) -> Dict[int, WorkerStatus]:
+        """Per-worker liveness + inflight leases; the basis of the
+        Manager's demand, straggler and dead-worker decisions."""
 
-        def heartbeat_view(self) -> Dict[int, WorkerStatus]:
-            """Per-worker liveness + inflight leases; the basis of the
-            Manager's demand, straggler and dead-worker decisions."""
-
-        def shutdown(self) -> None:
-            """Retire the pool; outstanding leases may be abandoned."""
-
-except ImportError:  # pragma: no cover - pre-3.8 fallback
-    WorkerBackend = object  # type: ignore[misc,assignment]
+    def shutdown(self) -> None:
+        """Retire the pool; outstanding leases may be abandoned."""
 
 
 def run_call_spec(spec: Tuple) -> Any:
@@ -1352,6 +1350,8 @@ class ProcessRpcBackend:
             raise RuntimeError("ProcessRpcBackend already started")
         import multiprocessing
         import uuid
+
+        device.refuse_child_processes_on_tpu("ProcessRpcBackend")
 
         self._session = uuid.uuid4().hex[:12]
         self._worker_stats = {}  # analysis: ok[locks] init phase, workers spawn below

@@ -45,7 +45,10 @@ def main(argv: Any = None) -> int:
     )
     args = parser.parse_args(argv)
 
+    from repro import device
     from repro.service.server import StudyServer
+
+    device.use_compile_cache()
 
     server = StudyServer.from_build(
         _resolve_build(args.build),

@@ -36,6 +36,7 @@ import os
 import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
+from repro import device
 from repro.core.params import ParamSet, ParamSpace, paramset
 from repro.core.sa import moat_indices, vbd_indices
 from repro.core.workflow import Workflow
@@ -556,6 +557,7 @@ def run_fleet_study(
     """
     if n_procs < 1:
         raise ValueError("run_fleet_study needs n_procs >= 1")
+    device.refuse_child_processes_on_tpu("run_fleet_study")
     # worker_backend crosses the spawn boundary via Pool initargs, so it
     # must be a picklable SPEC — None/"thread", or a module-level zero-arg
     # factory returning a WorkerBackend. A constructed backend instance

@@ -4,6 +4,9 @@ The critical invariant (paper §II-B): computation reuse is an optimization,
 never an approximation — every strategy must produce identical Dice vectors.
 """
 
+import hashlib
+import time
+
 import numpy as np
 import pytest
 
@@ -86,6 +89,29 @@ class TestOps:
         lab = ops.label_components(out, conn=8)
         n_comp = len({int(v) for v in np.unique(np.asarray(lab)) if v >= 0})
         assert n_comp >= 2  # split line separates the two discs
+
+
+class TestSyntheticTile:
+    # sha256 of the tile bytes as the full-image blob drawing produced them
+    PINNED = {
+        (48, 0): "33fac1e3bb219a78176145b7b356b60dc3f9911db083a4ff99b7658cc28aaaae",
+        (48, 1): "82818bd1ecf88f2d3ef6f1166d57a7fd67886b7bf7f8e49ebfbf273570ed5527",
+        (256, 0): "cd3e02d6b1304fffd73cc632c2e38346118e1a0e25d555959bf99a9b962974c3",
+        (256, 1): "8c84ada4126a6e04296b6f682b3440c4be9498f1ccbf6ace9c35d9bcecc1ead3",
+    }
+
+    @pytest.mark.parametrize("size,seed", sorted(PINNED))
+    def test_bytes_are_pinned(self, size, seed):
+        t = synthetic_tile(size, size, seed=seed)
+        assert t.dtype == np.float32 and t.shape == (size, size, 3)
+        assert hashlib.sha256(t.tobytes()).hexdigest() == self.PINNED[(size, seed)]
+
+    def test_paper_size_tile_builds_in_seconds(self):
+        t0 = time.perf_counter()
+        t = synthetic_tile(4096, 4096, seed=0)
+        elapsed = time.perf_counter() - t0
+        assert t.shape == (4096, 4096, 3)
+        assert elapsed < 30.0, f"4096x4096 tile took {elapsed:.1f}s"
 
 
 class TestStudy:

@@ -1,0 +1,106 @@
+"""Compile the pipeline's device programs for one TPU v5e chip at the
+paper's 4096x4096 tile size, without a chip attached.
+
+The TPU compiler is installed with JAX: it compiles for a described
+``v5e:2x2`` topology and refuses what the chip would refuse (kernels that
+cannot be lowered, programs that do not fit). Nothing runs, so these tests
+say nothing about results or times. The topology is described inside a
+module fixture, never at import time: only one process at a time may load
+the TPU library, and every test worker imports this file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.app import ops
+from repro.kernels.morph_recon import morph_reconstruct_pallas, tile_sweep
+from repro.kernels.ref import morph_reconstruct_ref
+
+SIZE = 4096
+HBM_BYTES = 16 << 30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without the chip; keep the cache out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _plane(dtype, sharding):
+    return jax.ShapeDtypeStruct((SIZE, SIZE), dtype, sharding=sharding)
+
+
+def _scalar(dtype, sharding):
+    return jax.ShapeDtypeStruct((), dtype, sharding=sharding)
+
+
+# name -> (jitted fn, static kwargs, argument builder, holds a Pallas kernel)
+CASES = {
+    "tile_sweep": (
+        tile_sweep, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), True,
+    ),
+    "morph_reconstruct_pallas": (
+        morph_reconstruct_pallas, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), True,
+    ),
+    "morph_reconstruct_ref": (
+        morph_reconstruct_ref, {"conn": 8},
+        lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), False,
+    ),
+    "fill_holes": (
+        ops.fill_holes, {"conn": 4},
+        lambda s: (_plane(jnp.bool_, s),), False,
+    ),
+    "area_filter": (
+        ops.area_filter, {"conn": 8},
+        lambda s: (_plane(jnp.bool_, s), _scalar(jnp.int32, s), _scalar(jnp.int32, s)),
+        False,
+    ),
+    "watershed_split": (
+        ops.watershed_split, {"conn": 8},
+        lambda s: (_plane(jnp.bool_, s), _scalar(jnp.int32, s)), False,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_compiles_for_one_v5e_chip_at_4k(name, one_chip):
+    fn, static, args, has_kernel = CASES[name]
+    compiled = fn.lower(*args(one_chip), **static).compile()
+    if has_kernel:
+        assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+        + mem.generated_code_size_in_bytes
+    )
+    assert total < HBM_BYTES, f"{name} needs {total} bytes on one chip"

@@ -62,7 +62,11 @@ def _array_of(seed):
 def _hang_until_killed(marker_dir):
     marker = pathlib.Path(marker_dir) / "pid"
     if not marker.exists():
-        marker.write_text(str(os.getpid()))
+        # write-then-rename: the test polls for existence, so the pid must
+        # be complete the instant the path appears
+        tmp = marker.with_suffix(".tmp")
+        tmp.write_text(str(os.getpid()))
+        os.replace(tmp, marker)
         time.sleep(60.0)
         return "hung"
     return "fast"

@@ -88,6 +88,42 @@ def instrumented_workflow(rng, injector):
     return Workflow(stages=stages), wf, names, cards
 
 
+def test_cold_second_stage_is_not_expired_by_the_first_stages_median():
+    """The streaming executor classes its buckets by stage. A cheap first
+    stage completes its buckets; the second stage's first bucket then runs
+    long (as a first jit compile does) past the deadline the first stage's
+    median would set, while a worker sits idle. No second-stage bucket may
+    be expired or cloned onto it, so each runs exactly once."""
+    compiled = threading.Event()
+    compiling = threading.Lock()
+    seg_calls = []
+
+    def norm(x):
+        return x + 1
+
+    def seg(x, p):
+        seg_calls.append(p)
+        # like jit: the first call compiles, concurrent callers wait for it
+        with compiling:
+            if not compiled.is_set():
+                time.sleep(0.5)
+                compiled.set()
+        return x * 10 + p
+
+    wf = Workflow(stages=(
+        StageSpec(name="norm", tasks=(TaskSpec("norm", (), fn=norm),)),
+        StageSpec(name="seg", tasks=(TaskSpec("seg", ("p",), fn=seg),)),
+    ))
+    inputs = [0, 1]
+    plan = plan_study(wf, [(("p", 7),)], policy="none")
+    stream = execute_study(
+        plan, inputs,
+        cluster=ClusterSpec(n_workers=3, heartbeat_timeout=0.1, straggler_factor=3.0),
+    )
+    assert [stream.outputs[i][0] for i in range(2)] == [17, 27]
+    assert len(seg_calls) == len(inputs)
+
+
 @pytest.mark.parametrize("policy", ["stage", "hybrid"])
 def test_transient_failures_leave_outputs_unchanged(policy):
     inj = Injector()
@@ -266,6 +302,26 @@ class TestPersistentManagerSessions:
         assert mgr.heartbeat_expiries >= 1
         assert mgr.retries >= 1
 
+    def test_first_bucket_of_a_costly_kind_is_not_expired(self):
+        """A cold first bucket (a jit compile) of a costly kind outlives
+        the deadline set by a cheap kind's completions: it is judged only
+        against its own kind, which has no history yet, so it is neither
+        expired nor cloned. Its second attempt would recompute it."""
+        mgr = Manager(heartbeat_timeout=0.1, straggler_factor=3.0)
+        runs = []
+
+        def compile_then_run():
+            runs.append(1)
+            time.sleep(0.5)  # 10x the deadline the cheap kind sets
+            return "seg"
+
+        for i in range(6):
+            mgr.submit(WorkItem(key=f"norm{i}", fn=lambda: "n", kind="norm"))
+        mgr.submit(WorkItem(key="seg0", fn=compile_then_run, kind="seg"))
+        out = mgr.run(3, expected=7)
+        assert out["seg0"] == "seg"
+        assert len(runs) == 1
+
 
 # ---------------------------------------------------------------------------
 # Work stealing under fire (ISSUE 7): steal storms + expired leases +
@@ -283,7 +339,11 @@ def _hier_hang_until_killed(marker_dir):
     hangs for the test to SIGKILL; retries return fast."""
     marker = pathlib.Path(marker_dir) / "pid"
     if not marker.exists():
-        marker.write_text(str(os.getpid()))
+        # write-then-rename: the test polls for existence, so the pid must
+        # be complete the instant the path appears
+        tmp = marker.with_suffix(".tmp")
+        tmp.write_text(str(os.getpid()))
+        os.replace(tmp, marker)
         time.sleep(60.0)
         return "hung"
     return "fast"
