@@ -1,0 +1,6 @@
+"""The device's ``peak_bytes_in_use`` after the window, in GiB."""
+
+
+def read(ctx):
+    peak = ctx["device"].get("memory_peak_bytes")
+    return peak / 2**30 if peak else None
