@@ -29,6 +29,7 @@ import heapq
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.reuse import ReuseNode, ReuseTree
+from repro.tracing import span
 
 __all__ = [
     "ScheduleResult",
@@ -206,6 +207,7 @@ def replay_schedule(
     *,
     lookup: Optional[Callable[[Tuple], Tuple[bool, Any]]] = None,
     store: Optional[Callable[[Tuple, Any, Any, Dict[str, Any]], None]] = None,
+    bucket: str = "",
 ) -> Tuple[Dict[int, Any], int, int]:
     """Replay a frozen schedule over a merged task tree.
 
@@ -217,6 +219,11 @@ def replay_schedule(
     ``lookup(path_key) -> (hit, value)`` / ``store(path_key, value, task,
     params)`` optionally plug a result cache in (the engine's run-level
     cache); the path key is the tuple of trie keys from the root.
+
+    Each executed node runs inside a span ``sa/task/<task name>``
+    (:func:`repro.tracing.span`) carrying ``bucket`` (the work key of the
+    bucket being replayed), the node's ``depth``, ``leaf`` (0/1) and
+    ``runs`` (its instances); a cache hit runs no task and has no span.
 
     Returns ``({run_id: leaf output}, tasks executed, cache hits)``.
     """
@@ -244,10 +251,14 @@ def replay_schedule(
             hits += 1
         else:
             src = input_state if at_root else outputs[parent.uid]
-            out = task.fn(src, **params) if task.fn is not None else src
-            executed += 1
-            if store is not None:
-                store(pk, out, task, params)
+            with span(
+                f"sa/task/{task.name}", bucket=bucket, depth=node.depth,
+                leaf=int(node.is_leaf), runs=len(node.instances),
+            ):
+                out = task.fn(src, **params) if task.fn is not None else src
+                executed += 1
+                if store is not None:
+                    store(pk, out, task, params)
         if node.is_leaf:
             for inst in node.instances:
                 results[inst.run_id] = out

@@ -21,14 +21,17 @@ reuse is bit-identical to recomputation.
 from __future__ import annotations
 
 import collections
+import contextlib
+import contextvars
 import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 from repro.core.rmsr import replay_schedule
 from repro.engine.types import BucketPlan, ClusterSpec, StudyPlan, StudyResult
 from repro.runtime.storage import HierarchicalStore
+from repro.tracing import span
 
-__all__ = ["ResultCache", "execute_bucket", "execute_plan"]
+__all__ = ["ResultCache", "bucket_key", "execute_bucket", "execute_plan"]
 
 
 class ResultCache:
@@ -170,6 +173,26 @@ class ResultCache:
             }
 
 
+# The WorkItem key of the bucket this thread is replaying, for its spans.
+_BUCKET_KEY: "contextvars.ContextVar[str]" = contextvars.ContextVar(
+    "bucket_key", default=""
+)
+
+
+@contextlib.contextmanager
+def bucket_key(key: str) -> Iterator[None]:
+    """Name ``key`` (the executor's WorkItem key, which holds the group's
+    key prefix and the input) in the spans of the buckets replayed inside.
+    The key travels with the thread's context rather than as an argument,
+    so that ``execute_bucket`` keeps the signature its callers and their
+    stand-ins share."""
+    token = _BUCKET_KEY.set(key)
+    try:
+        yield
+    finally:
+        _BUCKET_KEY.reset(token)
+
+
 def execute_bucket(
     bucket: BucketPlan,
     input_state: Any,
@@ -180,7 +203,11 @@ def execute_bucket(
     """Replay a bucket's frozen schedule (``rmsr.replay_schedule``) with the
     run-level cache plugged in under ``scope`` (default: the bucket's own
     cache scope; the streaming executor prefixes an input segment). Returns
-    ``(run_id -> leaf output, tasks executed, cache hits)``."""
+    ``(run_id -> leaf output, tasks executed, cache hits)``.
+
+    The replay runs inside a span ``sa/bucket`` carrying ``bucket``, the
+    work key set by :func:`bucket_key`, and the schedule's ``nodes``; each
+    task span inside it names the same key."""
     lookup = store = None
     if cache is not None:
         key_scope = bucket.cache_scope if scope is None else scope
@@ -191,9 +218,11 @@ def execute_bucket(
         def store(pk, out, task, params):
             cache.put(key_scope + (pk,), out, task.bound_bytes(params))
 
-    return replay_schedule(
-        bucket.tree, bucket.schedule.order, input_state, lookup=lookup, store=store
-    )
+    order, key = bucket.schedule.order, _BUCKET_KEY.get()
+    with span("sa/bucket", bucket=key, nodes=len(order)):
+        return replay_schedule(
+            bucket.tree, order, input_state, lookup=lookup, store=store, bucket=key
+        )
 
 
 def execute_plan(

@@ -46,6 +46,7 @@ from repro.engine.types import (
     StagePlan,
     StudyPlan,
 )
+from repro.tracing import span
 
 __all__ = ["TrieLedger", "plan_study"]
 
@@ -250,39 +251,40 @@ def plan_study(
         raise ValueError(f"unknown policy {policy!r}; expected one of {POLICIES}")
     memory = memory or MemoryBudget()
     param_sets = list(param_sets)
-    by_stage = workflow.instantiate(param_sets)
+    with span("sa/plan", runs=len(param_sets), policy=policy):
+        by_stage = workflow.instantiate(param_sets)
 
-    # Upstream signature per run: grows one element per planned stage; runs
-    # with equal signatures provably receive identical stage inputs.
-    upstream: Dict[int, tuple] = {rid: () for rid in range(len(param_sets))}
-    stage_plans: List[StagePlan] = []
-    for si, stage in enumerate(workflow.stages):
-        instances = by_stage[stage.name]
-        groups: Dict[tuple, List[StageInstance]] = {}
-        for inst in instances:
-            groups.setdefault(upstream[inst.run_id], []).append(inst)
-        buckets: List[BucketPlan] = []
-        for gkey in sorted(groups, key=repr):
-            buckets.extend(
-                _plan_group(
-                    si, stage, gkey, groups[gkey], policy, memory,
-                    max_bucket_size, active_paths, workers,
+        # Upstream signature per run: grows one element per planned stage;
+        # runs with equal signatures provably receive identical stage inputs.
+        upstream: Dict[int, tuple] = {rid: () for rid in range(len(param_sets))}
+        stage_plans: List[StagePlan] = []
+        for si, stage in enumerate(workflow.stages):
+            instances = by_stage[stage.name]
+            groups: Dict[tuple, List[StageInstance]] = {}
+            for inst in instances:
+                groups.setdefault(upstream[inst.run_id], []).append(inst)
+            buckets: List[BucketPlan] = []
+            for gkey in sorted(groups, key=repr):
+                buckets.extend(
+                    _plan_group(
+                        si, stage, gkey, groups[gkey], policy, memory,
+                        max_bucket_size, active_paths, workers,
+                    )
+                )
+            stage_plans.append(
+                StagePlan(
+                    stage=stage,
+                    index=si,
+                    buckets=buckets,
+                    tasks_total=len(instances) * len(stage.tasks),
                 )
             )
-        stage_plans.append(
-            StagePlan(
-                stage=stage,
-                index=si,
-                buckets=buckets,
-                tasks_total=len(instances) * len(stage.tasks),
-            )
-        )
-        for inst in instances:
-            upstream[inst.run_id] = upstream[inst.run_id] + (inst.task_keys(),)
+            for inst in instances:
+                upstream[inst.run_id] = upstream[inst.run_id] + (inst.task_keys(),)
 
-    ledger_pending = (
-        _annotate_with_ledger(stage_plans, ledger) if ledger is not None else None
-    )
+        ledger_pending = (
+            _annotate_with_ledger(stage_plans, ledger) if ledger is not None else None
+        )
 
     return StudyPlan(
         workflow=workflow,

@@ -32,7 +32,7 @@ import threading
 import time
 from typing import Any, List, Optional, Sequence
 
-from repro.engine.executor import ResultCache, execute_bucket
+from repro.engine.executor import ResultCache, bucket_key, execute_bucket
 from repro.engine.types import (
     ClusterSpec,
     StudyPlan,
@@ -40,6 +40,7 @@ from repro.engine.types import (
     StudyStreamResult,
 )
 from repro.runtime.manager import Manager, TaskCancelled, WorkItem
+from repro.tracing import span
 
 __all__ = ["execute_study", "study_task_keys"]
 
@@ -241,44 +242,63 @@ def execute_study(
         if cancel_event is not None and cancel_event.is_set():
             return
         stage_plan = plan.stages[si]
-        st = states[i]
-        for bi, bucket in enumerate(stage_plan.buckets):
-            src = st.current[bucket.run_ids[0]]
-            key = f"{key_prefix}in{i}:{stage_plan.index}:{stage_plan.stage.name}:{bi}"
-            submitted.append(key)
-            with lock:
-                outstanding[0] += 1
-            # a shared submit of an already-settled key fires the callback
-            # synchronously on THIS thread — the lock is not held here
-            mgr.submit(
-                WorkItem(
-                    key=key,
-                    fn=lambda b=bucket, s=src, k=input_keys[i]: execute_bucket(
-                        b, s, cache, scope=("input", k) + b.cache_scope
-                    ),
-                    # spec-capable backends ship this instead of the
-                    # closure; workers hold the same plan (rebuilt from the
-                    # recipe) and resolve src from the shared store
-                    spec=("bucket", plan_id, i, si, bi) if spec_mode else None,
-                    # reuse-tree prefix for locality-aware hierarchical
-                    # dispatch: input first (stage s+1 chases stage s's
-                    # worker), then the bucket's trie scope
-                    path=(f"{key_prefix}{input_keys[i]}",) + bucket.cache_scope,
-                    # stages differ in cost by orders of magnitude, and each
-                    # one's first bucket compiles: judge stragglers per stage
-                    kind=stage_plan.stage.name,
-                    callback=lambda _key, value, i=i, si=si: on_bucket(i, si, value),
-                    shared=shared,
-                    tenant=tenant,
-                    priority=priority,
+        name, buckets = stage_plan.stage.name, stage_plan.buckets
+        with span(
+            "sa/submit_stage", prefix=key_prefix, input=i, stage=name,
+            buckets=len(buckets),
+        ):
+            for bi, bucket in enumerate(buckets):
+                src = states[i].current[bucket.run_ids[0]]
+                key = f"{key_prefix}in{i}:{stage_plan.index}:{name}:{bi}"
+                submitted.append(key)
+                with lock:
+                    outstanding[0] += 1
+                # a shared submit of an already-settled key fires the
+                # callback synchronously on THIS thread — the lock is not
+                # held here
+                mgr.submit(
+                    WorkItem(
+                        key=key,
+                        fn=lambda b=bucket, s=src, k=input_keys[i], w=key: run_bucket(
+                            w, b, s, k
+                        ),
+                        # spec-capable backends ship this instead of the
+                        # closure; workers hold the same plan (rebuilt from
+                        # the recipe) and resolve src from the shared store
+                        spec=("bucket", plan_id, i, si, bi) if spec_mode else None,
+                        # reuse-tree prefix for locality-aware hierarchical
+                        # dispatch: input first (stage s+1 chases stage s's
+                        # worker), then the bucket's trie scope
+                        path=(f"{key_prefix}{input_keys[i]}",) + bucket.cache_scope,
+                        # stages differ in cost by orders of magnitude, and
+                        # each one's first bucket compiles: judge stragglers
+                        # per stage
+                        kind=name,
+                        callback=lambda key, value, i=i, si=si: on_bucket(
+                            i, si, key, value
+                        ),
+                        shared=shared,
+                        tenant=tenant,
+                        priority=priority,
+                    )
                 )
+
+    def run_bucket(key: str, bucket, src: Any, input_key: Any):
+        with bucket_key(key):
+            return execute_bucket(
+                bucket, src, cache, scope=("input", input_key) + bucket.cache_scope
             )
 
-    def on_bucket(i: int, si: int, value: Any) -> None:
+    def on_bucket(i: int, si: int, key: str, value: Any) -> None:
         """Per-item completion callback (Manager pump thread, outside the
-        Manager lock): fold the bucket into input i's stage accumulator;
-        when the stage closes, route outputs and submit the next stage —
-        the per-input dependency edge."""
+        Manager lock), inside a span ``sa/settle`` naming the bucket's work
+        key: fold the bucket into input i's stage accumulator; when the
+        stage closes, route outputs and submit the next stage — the
+        per-input dependency edge."""
+        with span("sa/settle", bucket=key):
+            _settle_bucket(i, si, value)
+
+    def _settle_bucket(i: int, si: int, value: Any) -> None:
         st = states[i]
         advance = False
         with lock:

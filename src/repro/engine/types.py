@@ -253,6 +253,13 @@ class StudyStreamResult:
     spans all inputs. ``busy_seconds`` sums the winning attempts' wall-times,
     so ``parallel_efficiency`` matches the paper's busy/(makespan×workers)
     definition.
+
+    On an asynchronous device (a TPU) an attempt's wall-time is the time to
+    launch its programs, plus any wait for a slot in PJRT's limit on
+    programs in flight; it is not device work, so ``busy_seconds`` and
+    ``parallel_efficiency`` read the launch, not the chip. Device time per
+    task comes from a profiler trace joined to the task spans
+    (``sa/task/*``; the benchmark's ``bench/spans.py``).
     """
 
     outputs: Dict[int, Dict[int, Any]]
@@ -290,6 +297,8 @@ class StudyStreamResult:
 
     @property
     def parallel_efficiency(self) -> float:
+        """busy / (wall × workers); on an asynchronous device it times the
+        launches, not device work (see the class docstring)."""
         from repro.core.metrics import parallel_efficiency
 
         return parallel_efficiency(

@@ -61,6 +61,7 @@ keeps the flat single-pump Manager byte-for-byte.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -80,6 +81,7 @@ from repro.runtime.transport import (
     WorkerStatus,
     make_backend,
 )
+from repro.tracing import span
 
 __all__ = ["WorkItem", "Manager", "TaskCancelled", "run_study_distributed"]
 
@@ -299,7 +301,11 @@ class Manager:
     @property
     def busy_seconds(self) -> float:
         """Sum of winning-attempt wall-times — the useful-work numerator of
-        the parallel-efficiency accounting."""
+        the parallel-efficiency accounting. Where tasks launch programs on
+        an asynchronous device (a TPU), an attempt's wall-time is the launch
+        plus any wait on PJRT's limit on programs in flight, not device
+        work; the device time of each task comes from a profiler trace
+        joined to the task spans (``sa/task/*``)."""
         with self._lock:
             return self._busy_total
 
@@ -308,7 +314,10 @@ class Manager:
         fanout, work-stealing and locality counters, pump occupancy (the
         fraction of session wall-time each pump spent doing scheduling
         work — the serialization metric the hierarchy exists to fix), and
-        per-worker busy seconds / mean idle fraction."""
+        per-worker busy seconds / mean idle fraction. ``worker_busy_seconds``
+        sums attempt wall-times, which on an asynchronous device time the
+        launch plus any wait on PJRT's in-flight limit, not device work (see
+        :attr:`busy_seconds`)."""
         now = time.monotonic()
         with self._lock:
             t0 = self._session_t0
@@ -1328,6 +1337,38 @@ class Manager:
                     self._parked_since = None
             comps = backend.poll_completions(0.0 if just_woke else _IDLE_TICK)
             t_work = time.monotonic()
+            self._pump_tick(backend, comps, hier)
+            with self._cond:
+                self._pump_busy += time.monotonic() - t_work
+                if (
+                    self._state == _CLOSING
+                    and not self._pending
+                    and not self._running
+                    and not self._queue
+                    and not any(s.queue for s in self._subs)
+                ):
+                    return
+
+    def _pump_tick(self, backend, comps: List[Completion], hier: bool) -> None:
+        """One pump tick's work: settle ``comps``, expire dead and stale
+        leases, then offer leases to free workers (or, hierarchical,
+        delegate them to the sub-pumps). The tick runs inside a span
+        ``sa/dispatch`` (``completions``, ``leases``), opened at its first
+        completion or else at its first lease; a tick that settles no
+        completion and mints no lease records none."""
+        leases = 0
+        with contextlib.ExitStack() as tick:
+            mark = None
+
+            def open_tick() -> None:
+                nonlocal mark
+                if mark is None:
+                    mark = tick.enter_context(
+                        span("sa/dispatch", completions=len(comps))
+                    )
+
+            if comps:
+                open_tick()
             for comp in comps:
                 self._handle_completion(comp)
             view = backend.heartbeat_view()
@@ -1376,7 +1417,8 @@ class Manager:
                 # sub-pumps own demand-driven dispatch for their shards
                 # (parked sub-pumps are woken when items land in shards)
                 with self._cond:
-                    if self._distribute_locked():
+                    leases = self._distribute_locked()
+                    if leases:
                         self._cond.notify_all()
             else:
                 # demand-driven dispatch: free slots = per-worker queue
@@ -1392,13 +1434,14 @@ class Manager:
                 )
                 offer_batch = getattr(backend, "offer_batch", None)
                 if offer_batch is not None:
-                    self._dispatch_batched(offer_batch, free)
+                    leases = self._dispatch_batched(offer_batch, free, open_tick)
                 else:
                     while free > 0:
                         with self._cond:
                             item = self._next_locked()
                         if item is None:
                             break
+                        open_tick()
                         lease = Lease(
                             key=item.key, attempt=item.attempts, fn=item.fn,
                             spec=item.spec,
@@ -1410,28 +1453,26 @@ class Manager:
                                     + 1
                                 )
                             free -= 1
+                            leases += 1
                         else:  # slot vanished since snapshot (worker death)
                             with self._cond:
                                 self._unlease_locked(item)
                             break
-            with self._cond:
-                self._pump_busy += time.monotonic() - t_work
-                if (
-                    self._state == _CLOSING
-                    and not self._pending
-                    and not self._running
-                    and not self._queue
-                    and not any(s.queue for s in self._subs)
-                ):
-                    return
+            if mark is not None:
+                mark.set_metadata(leases=leases)
 
-    def _dispatch_batched(self, offer_batch, free: int) -> None:
+    def _dispatch_batched(
+        self, offer_batch, free: int, on_batch: Callable[[], None]
+    ) -> int:
         """Batched dispatch (DESIGN.md §14): lease up to ``free`` items in
         one pass and hand them to the backend as a single ``offer_batch``
         call — the backend coalesces each worker's share into one frame.
         Rejected leases (slots vanished since the demand snapshot) are
         unleased in reverse lease order, restoring queue position and
-        attempt numbers exactly as the one-at-a-time path would."""
+        attempt numbers exactly as the one-at-a-time path would.
+        ``on_batch`` runs once a batch is leased, before it is offered.
+        Returns the number of leases the backend accepted."""
+        offered = 0
         while free > 0:
             batch: List = []
             with self._cond:
@@ -1441,13 +1482,15 @@ class Manager:
                         break
                     batch.append(item)
             if not batch:
-                return
+                return offered
+            on_batch()
             leases = [
                 Lease(key=it.key, attempt=it.attempts, fn=it.fn, spec=it.spec)
                 for it in batch
             ]
             rejected = {lease.lease_id for lease in offer_batch(leases)}
             accepted = len(batch) - len(rejected)
+            offered += accepted
             if accepted:
                 with self._cond:
                     self.dispatch_counts[self.backend_name] = (
@@ -1458,8 +1501,9 @@ class Manager:
                     for it in reversed(batch):
                         if f"{it.key}#{it.attempts}" in rejected:
                             self._unlease_locked(it)
-                return
+                return offered
             free -= accepted
+        return offered
 
     # ------------------------------------------------------------------
     # One-shot batch mode (the pre-streaming API, kept verbatim)

@@ -951,14 +951,15 @@ class _RpcWorker:
                     plan_id,
                 )
                 src = self._resolve_upstream(up_key)[rid0]
-            from repro.engine.executor import execute_bucket
+            from repro.engine.executor import bucket_key, execute_bucket
 
-            value, executed, hits = execute_bucket(
-                bucket,
-                src,
-                self.cache if pm["cache_enabled"] else None,
-                scope=("input", pm["input_keys"][input_idx]) + bucket.cache_scope,
-            )
+            with bucket_key(work_key):
+                value, executed, hits = execute_bucket(
+                    bucket,
+                    src,
+                    self.cache if pm["cache_enabled"] else None,
+                    scope=("input", pm["input_keys"][input_idx]) + bucket.cache_scope,
+                )
             meta = {"wrap": "bucket", "executed": executed, "hits": hits}
         else:
             raise TransportError(f"unknown lease spec kind {kind!r}")
