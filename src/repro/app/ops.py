@@ -3,11 +3,13 @@
 The motivating application normalises a whole-slide H&E tile, segments cell
 nuclei through a chain of threshold / morphological operators, and compares
 each run's mask with the default-parameter mask (Dice). Every operator below
-is a pure, jittable function on ``float32``/``bool`` arrays; the propagation
-hot-spot (morphological reconstruction, also the engine behind fill-holes and
-the watershed flooding) has a Pallas TPU kernel in
-``repro.kernels.morph_recon``; the pipeline runs the same XLA loop on every
-backend and calls the kernel only when asked to by name.
+is a pure, jittable function on ``float32``/``bool`` arrays. Fill-holes runs
+the bit-packed Pallas kernel of ``repro.kernels.fill_holes`` on a TPU, where
+the tile's packed planes fit its VMEM budget, and the XLA reconstruction loop
+elsewhere. The other propagation loops (the reconstruction, labelling, the
+watershed flood) run as XLA loops on every backend; the float32
+reconstruction kernel of ``repro.kernels.morph_recon`` runs only when asked
+for by name.
 
 Connectivity parameters (FH / RC / WConn in Table I) are 4 or 8 and must be
 *static* under jit (they select the structuring element).
@@ -83,18 +85,12 @@ def morph_reconstruct(
 
 @functools.partial(jax.jit, static_argnames=("conn",))
 def fill_holes(mask: jax.Array, conn: int = 4) -> jax.Array:
-    """Binary fill-holes via reconstruction of the complement from the border
-    (FH parameter selects the propagation neighbourhood)."""
-    from repro.kernels import ref as kref
+    """Binary fill-holes: background the border cannot reach becomes
+    foreground (FH parameter selects the propagation neighbourhood). The
+    Pallas kernel on a TPU, the XLA loop elsewhere (``kernels.ops``)."""
+    from repro.kernels import ops as kops
 
-    inv = (~mask).astype(jnp.float32)
-    border = jnp.zeros_like(inv)
-    border = border.at[0, :].set(inv[0, :])
-    border = border.at[-1, :].set(inv[-1, :])
-    border = border.at[:, 0].set(inv[:, 0])
-    border = border.at[:, -1].set(inv[:, -1])
-    outside = kref.morph_reconstruct_ref(border, inv, conn=conn)
-    return mask | (outside < 0.5)
+    return kops.fill_holes(mask, conn=conn)
 
 
 @functools.partial(jax.jit, static_argnames=("conn",))

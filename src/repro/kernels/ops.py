@@ -4,7 +4,9 @@ With ``use_kernel=None`` a wrapper runs the Pallas kernel on a TPU backend
 and the pure-XLA reference path elsewhere. ``use_kernel=True`` asks for the
 kernel, which is an error off a TPU: the Pallas interpreter runs only where
 a caller of the kernel itself asks for it by name (``interpret=True``, as
-the kernel tests do).
+the kernel tests do). ``fill_holes`` takes no such option: it runs the
+kernel on a TPU wherever the tile's packed planes fit the kernel's VMEM
+budget, and the XLA loop otherwise.
 """
 
 from __future__ import annotations
@@ -51,6 +53,16 @@ def morph_reconstruct(
             inner_iters=inner_iters,
         )
     return kref.morph_reconstruct_ref(marker, mask, conn=conn)
+
+
+def fill_holes(mask: jax.Array, *, conn: int = 4) -> jax.Array:
+    """Binary fill-holes (see kernels/fill_holes.py): on a TPU the bit-packed
+    VMEM kernel where its two planes fit ``VMEM_BUDGET``, else the XLA loop."""
+    from repro.kernels import fill_holes as kfill
+
+    if _want_kernel(None, "fill_holes") and kfill.fits_vmem(*mask.shape):
+        return kfill.fill_holes_pallas(mask, conn=conn)
+    return kref.fill_holes_ref(mask, conn=conn)
 
 
 def flash_attention(

@@ -19,6 +19,7 @@ __all__ = [
     "dilate",
     "erode",
     "morph_reconstruct_ref",
+    "fill_holes_ref",
     "attention_ref",
     "ssm_scan_ref",
 ]
@@ -80,6 +81,19 @@ def morph_reconstruct_ref(marker: jax.Array, mask: jax.Array, conn: int = 8) -> 
 
     out, _ = jax.lax.while_loop(lambda s: s[1], body, (marker, jnp.bool_(True)))
     return out
+
+
+def fill_holes_ref(mask: jax.Array, conn: int = 4) -> jax.Array:
+    """Binary fill-holes via reconstruction of the complement from the
+    border (oracle for kernels/fill_holes.py, and the path off a TPU)."""
+    inv = (~mask).astype(jnp.float32)
+    border = jnp.zeros_like(inv)
+    border = border.at[0, :].set(inv[0, :])
+    border = border.at[-1, :].set(inv[-1, :])
+    border = border.at[:, 0].set(inv[:, 0])
+    border = border.at[:, -1].set(inv[:, -1])
+    outside = morph_reconstruct_ref(border, inv, conn=conn)
+    return mask | (outside < 0.5)
 
 
 # ---------------------------------------------------------------------------

@@ -18,11 +18,13 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.app import ops
+from repro.kernels.fill_holes import fill_holes_pallas
 from repro.kernels.morph_recon import morph_reconstruct_pallas, tile_sweep
 from repro.kernels.ref import morph_reconstruct_ref
 
 SIZE = 4096
 HBM_BYTES = 16 << 30  # one v5e chip
+FEW_MIB = 8 << 20  # HBM a VMEM-resident kernel may need besides its planes
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +80,14 @@ CASES = {
         ops.fill_holes, {"conn": 4},
         lambda s: (_plane(jnp.bool_, s),), False,
     ),
+    "fill_holes_pallas_conn4": (
+        fill_holes_pallas, {"conn": 4, "interpret": False},
+        lambda s: (_plane(jnp.bool_, s),), True,
+    ),
+    "fill_holes_pallas_conn8": (
+        fill_holes_pallas, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.bool_, s),), True,
+    ),
     "area_filter": (
         ops.area_filter, {"conn": 8},
         lambda s: (_plane(jnp.bool_, s), _scalar(jnp.int32, s), _scalar(jnp.int32, s)),
@@ -104,3 +114,14 @@ def test_compiles_for_one_v5e_chip_at_4k(name, one_chip):
         + mem.generated_code_size_in_bytes
     )
     assert total < HBM_BYTES, f"{name} needs {total} bytes on one chip"
+
+
+@pytest.mark.parametrize("conn", [4, 8])
+def test_fill_holes_kernel_needs_few_mib_of_hbm_besides_its_planes(conn, one_chip):
+    """The packed planes live in VMEM: HBM holds the bool mask in, the bool
+    result out and little else (the XLA loop holds float32 planes)."""
+    mem = fill_holes_pallas.lower(_plane(jnp.bool_, one_chip), conn=conn).compile().memory_analysis()
+    plane = SIZE * SIZE  # one bool plane
+    assert mem.argument_size_in_bytes == plane
+    extra = mem.output_size_in_bytes - plane + mem.temp_size_in_bytes
+    assert extra <= FEW_MIB, f"fill_holes kernel needs {extra} bytes of HBM besides its planes"
