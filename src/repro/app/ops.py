@@ -3,13 +3,13 @@
 The motivating application normalises a whole-slide H&E tile, segments cell
 nuclei through a chain of threshold / morphological operators, and compares
 each run's mask with the default-parameter mask (Dice). Every operator below
-is a pure, jittable function on ``float32``/``bool`` arrays. Fill-holes runs
-the bit-packed Pallas kernel of ``repro.kernels.fill_holes`` on a TPU, where
-the tile's packed planes fit its VMEM budget, and the XLA reconstruction loop
-elsewhere. The other propagation loops (the reconstruction, labelling, the
-watershed flood) run as XLA loops on every backend; the float32
-reconstruction kernel of ``repro.kernels.morph_recon`` runs only when asked
-for by name.
+is a pure, jittable function on ``float32``/``bool`` arrays. On a TPU,
+fill-holes runs the bit-packed Pallas kernel of ``repro.kernels.fill_holes``
+(where the tile's packed planes fit its VMEM budget), and component labels
+and the watershed flood run the row-blocked Pallas kernel of
+``repro.kernels.label``; off a TPU each runs its XLA loop. The reconstruction
+runs as an XLA loop on every backend; the float32 reconstruction kernel of
+``repro.kernels.morph_recon`` runs only when asked for by name.
 
 Connectivity parameters (FH / RC / WConn in Table I) are 4 or 8 and must be
 *static* under jit (they select the structuring element).
@@ -98,26 +98,11 @@ def label_components(mask: jax.Array, conn: int = 8) -> jax.Array:
     """Connected-component labels by iterative min-label propagation.
 
     Labels are flat pixel indices (stable, deterministic); background = -1.
-    The loop runs until fixpoint — bounded by the component diameter.
+    The Pallas kernel on a TPU, the XLA loop elsewhere (``kernels.ops``).
     """
-    h, w = mask.shape
-    idx = jnp.arange(h * w, dtype=jnp.int32).reshape(h, w)
-    big = jnp.int32(h * w)
-    labels = jnp.where(mask, idx, big)
+    from repro.kernels import ops as kops
 
-    def body(state):
-        lab, _ = state
-        new = lab
-        for dy, dx in _neighbors(conn):
-            new = jnp.minimum(new, _shift(lab, dy, dx, big))
-        new = jnp.where(mask, new, big)
-        return new, jnp.any(new != lab)
-
-    def cond(state):
-        return state[1]
-
-    labels, _ = jax.lax.while_loop(cond, body, (labels, jnp.bool_(True)))
-    return jnp.where(mask, labels, -1)
+    return kops.label_components(mask, conn=conn)
 
 
 @jax.jit
@@ -167,6 +152,8 @@ def watershed_split(
     wavefront propagation); pixels where two different seeds collide form the
     split lines, which are removed from the mask. Components smaller than
     ``min_size_pl`` are dropped *before* splitting (paper's MinSizePl)."""
+    from repro.kernels import ops as kops
+
     pre = mask & (component_sizes(label_components(mask, conn=conn)) >= min_size_pl)
     dist = distance_transform(pre, conn=4)
     maxima = (dist >= dilate(dist, conn=conn)) & pre & (dist > 1.0)
@@ -175,19 +162,8 @@ def watershed_split(
     # merge plateau maxima into one seed per regional maximum
     seed_labels = label_components(maxima, conn=8)
     seeds = jnp.where(maxima, seed_labels, big)
-
-    def body(state):
-        """Competitive multi-source BFS: unlabeled pixels take the min
-        neighbouring label; labelled pixels never change, so basins stop at
-        collision fronts (the watershed lines)."""
-        lab, _ = state
-        nb = jnp.full_like(lab, big)
-        for dy, dx in _neighbors(conn):
-            nb = jnp.minimum(nb, _shift(lab, dy, dx, big))
-        new = jnp.where((lab == big) & pre, nb, lab)
-        return new, jnp.any(new != lab)
-
-    lab, _ = jax.lax.while_loop(lambda s: s[1], body, (seeds, jnp.bool_(True)))
+    # competitive multi-source BFS: basins stop at collision fronts
+    lab = kops.flood(seeds, pre, conn=conn)
     # split line: a pixel adjacent (4-conn) to a pixel of a different basin
     boundary = jnp.zeros_like(mask)
     for dy, dx in _neighbors(4):

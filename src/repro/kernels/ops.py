@@ -4,9 +4,11 @@ With ``use_kernel=None`` a wrapper runs the Pallas kernel on a TPU backend
 and the pure-XLA reference path elsewhere. ``use_kernel=True`` asks for the
 kernel, which is an error off a TPU: the Pallas interpreter runs only where
 a caller of the kernel itself asks for it by name (``interpret=True``, as
-the kernel tests do). ``fill_holes`` takes no such option: it runs the
-kernel on a TPU wherever the tile's packed planes fit the kernel's VMEM
-budget, and the XLA loop otherwise.
+the kernel tests do). ``fill_holes``, ``label_components`` and ``flood``
+take no such option: ``fill_holes`` runs its kernel on a TPU wherever the
+tile's packed planes fit the kernel's VMEM budget, the other two run theirs
+on a TPU at every shape (their row blocks follow from the width), and each
+runs its XLA loop elsewhere.
 """
 
 from __future__ import annotations
@@ -63,6 +65,26 @@ def fill_holes(mask: jax.Array, *, conn: int = 4) -> jax.Array:
     if _want_kernel(None, "fill_holes") and kfill.fits_vmem(*mask.shape):
         return kfill.fill_holes_pallas(mask, conn=conn)
     return kref.fill_holes_ref(mask, conn=conn)
+
+
+def label_components(mask: jax.Array, *, conn: int = 8) -> jax.Array:
+    """Connected-component labels (see kernels/label.py): the row-blocked
+    VMEM kernel on a TPU, else the XLA loop."""
+    if _want_kernel(None, "label_components"):
+        from repro.kernels.label import label_components_pallas
+
+        return label_components_pallas(mask, conn=conn)
+    return kref.label_components_ref(mask, conn=conn)
+
+
+def flood(seeds: jax.Array, pre: jax.Array, *, conn: int = 8) -> jax.Array:
+    """The watershed's seeded flood through ``pre`` (see kernels/label.py):
+    the row-blocked VMEM kernel on a TPU, else the XLA loop."""
+    if _want_kernel(None, "flood"):
+        from repro.kernels.label import flood_pallas
+
+        return flood_pallas(seeds, pre, conn=conn)
+    return kref.flood_ref(seeds, pre, conn=conn)
 
 
 def flash_attention(

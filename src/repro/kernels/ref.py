@@ -20,6 +20,8 @@ __all__ = [
     "erode",
     "morph_reconstruct_ref",
     "fill_holes_ref",
+    "label_components_ref",
+    "flood_ref",
     "attention_ref",
     "ssm_scan_ref",
 ]
@@ -94,6 +96,55 @@ def fill_holes_ref(mask: jax.Array, conn: int = 4) -> jax.Array:
     border = border.at[:, -1].set(inv[:, -1])
     outside = morph_reconstruct_ref(border, inv, conn=conn)
     return mask | (outside < 0.5)
+
+
+def label_components_ref(mask: jax.Array, conn: int = 8) -> jax.Array:
+    """Connected-component labels by iterative min-label propagation
+    (oracle for kernels/label.py, and the path off a TPU).
+
+    Labels are flat pixel indices (stable, deterministic); background = -1.
+    The loop runs until fixpoint — bounded by the component diameter.
+    """
+    h, w = mask.shape
+    idx = jnp.arange(h * w, dtype=jnp.int32).reshape(h, w)
+    big = jnp.int32(h * w)
+    labels = jnp.where(mask, idx, big)
+
+    def body(state):
+        lab, _ = state
+        new = lab
+        for dy, dx in neighbors(conn):
+            new = jnp.minimum(new, shift2d(lab, dy, dx, big))
+        new = jnp.where(mask, new, big)
+        return new, jnp.any(new != lab)
+
+    def cond(state):
+        return state[1]
+
+    labels, _ = jax.lax.while_loop(cond, body, (labels, jnp.bool_(True)))
+    return jnp.where(mask, labels, -1)
+
+
+def flood_ref(seeds: jax.Array, pre: jax.Array, conn: int = 8) -> jax.Array:
+    """The watershed's seeded flood: ``seeds`` holds each seed pixel's label
+    and ``h * w`` elsewhere (oracle for kernels/label.py, and the path off
+    a TPU)."""
+    h, w = seeds.shape
+    big = jnp.int32(h * w)
+
+    def body(state):
+        """Competitive multi-source BFS: unlabeled pixels take the min
+        neighbouring label; labelled pixels never change, so basins stop at
+        collision fronts (the watershed lines)."""
+        lab, _ = state
+        nb = jnp.full_like(lab, big)
+        for dy, dx in neighbors(conn):
+            nb = jnp.minimum(nb, shift2d(lab, dy, dx, big))
+        new = jnp.where((lab == big) & pre, nb, lab)
+        return new, jnp.any(new != lab)
+
+    lab, _ = jax.lax.while_loop(lambda s: s[1], body, (seeds, jnp.bool_(True)))
+    return lab
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.app import ops
+from repro.kernels import label as klabel
 from repro.kernels.fill_holes import fill_holes_pallas
+from repro.kernels.label import flood_pallas, label_components_pallas
 from repro.kernels.morph_recon import morph_reconstruct_pallas, tile_sweep
 from repro.kernels.ref import morph_reconstruct_ref
 
@@ -88,6 +90,22 @@ CASES = {
         fill_holes_pallas, {"conn": 8, "interpret": False},
         lambda s: (_plane(jnp.bool_, s),), True,
     ),
+    "label_components_pallas_conn4": (
+        label_components_pallas, {"conn": 4, "interpret": False},
+        lambda s: (_plane(jnp.bool_, s),), True,
+    ),
+    "label_components_pallas_conn8": (
+        label_components_pallas, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.bool_, s),), True,
+    ),
+    "flood_pallas_conn4": (
+        flood_pallas, {"conn": 4, "interpret": False},
+        lambda s: (_plane(jnp.int32, s), _plane(jnp.bool_, s)), True,
+    ),
+    "flood_pallas_conn8": (
+        flood_pallas, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.int32, s), _plane(jnp.bool_, s)), True,
+    ),
     "area_filter": (
         ops.area_filter, {"conn": 8},
         lambda s: (_plane(jnp.bool_, s), _scalar(jnp.int32, s), _scalar(jnp.int32, s)),
@@ -125,3 +143,22 @@ def test_fill_holes_kernel_needs_few_mib_of_hbm_besides_its_planes(conn, one_chi
     assert mem.argument_size_in_bytes == plane
     extra = mem.output_size_in_bytes - plane + mem.temp_size_in_bytes
     assert extra <= FEW_MIB, f"fill_holes kernel needs {extra} bytes of HBM besides its planes"
+
+
+@pytest.mark.parametrize("conn", [4, 8])
+def test_label_kernels_need_no_hbm_plane_beyond_the_relaxed_ones(conn, one_chip):
+    """The blocks settle in VMEM: besides its arguments and result, HBM
+    holds the padded planes being relaxed (one for labels, distance and
+    label for the flood) and little else (the XLA label loop holds about
+    thirteen int32 planes)."""
+    rows, blocks, wp = klabel.layout(SIZE, SIZE, 2)
+    padded = (blocks * rows + 2 * klabel.HALO) * wp * 4  # one padded int32 plane
+    bools, ints = _plane(jnp.bool_, one_chip), _plane(jnp.int32, one_chip)
+    mem = label_components_pallas.lower(bools, conn=conn).compile().memory_analysis()
+    assert mem.argument_size_in_bytes == SIZE * SIZE
+    assert mem.output_size_in_bytes == 4 * SIZE * SIZE
+    assert mem.temp_size_in_bytes <= padded, mem.temp_size_in_bytes
+    mem = flood_pallas.lower(ints, bools, conn=conn).compile().memory_analysis()
+    assert mem.argument_size_in_bytes == 5 * SIZE * SIZE
+    assert mem.output_size_in_bytes == 4 * SIZE * SIZE
+    assert mem.temp_size_in_bytes <= 2 * padded, mem.temp_size_in_bytes
