@@ -1,4 +1,4 @@
-"""Streaming dataset executor benchmark (DESIGN.md §10, §15) — the perf
+"""Streaming dataset executor benchmark (DESIGN.md §10) — the perf
 trajectory's machine-readable series (``BENCH_streaming.json``).
 
 Three measurements:
@@ -6,25 +6,20 @@ Three measurements:
 (1) **real** — a multi-tile study on small tiles with real JAX tasks:
     K sequential ``execute_plan`` calls (one Manager session per call)
     versus one ``execute_study`` over the same tiles (one persistent
-    session, per-tile stage edges) at 1/2/4 Workers, plus the same study
-    through the HIERARCHICAL scheduler (fanout=2 sub-manager pumps,
-    locality + stealing). Every row reports the scheduler observables —
-    pump occupancy, mean worker idle fraction, locality hit-rate — and
-    hierarchical outputs are asserted bit-identical to flat.
+    session, per-tile stage edges) at 1/2/4 Workers. Every row reports the
+    scheduler observables — pump occupancy and mean worker idle fraction —
+    and streaming outputs are asserted bit-identical to sequential.
 
 (2) **paper scale** — the discrete-event streaming model
     (``runtime.simulate_stream``) fed by the hybrid plan's frozen per-stage
     bucket makespans (measured JAX costs scaled to 4K×4K tiles), the full
-    6,113 tiles at 32→256 nodes × 28 cores. The flat single pump is
-    charged ``PUMP_SERVICE`` per scheduling event (the measured
-    order-of-magnitude of the Python pump's per-event cost — see the real
-    rows' pump occupancy), which saturates it at 256 nodes
-    (occupancy ≈ 0.87); fanout=16 sub-pumps with locality + stealing
-    recover the paper's regime. Paper claim: >92% efficiency at 256 nodes;
-    the artifact records the ``EFF_FLOOR`` gate CI enforces.
+    6,113 tiles at 32→256 nodes × 28 cores. The single pump is charged
+    ``PUMP_SERVICE`` per scheduling event (the measured order-of-magnitude
+    of the Python pump's per-event cost — see the real rows' pump
+    occupancy). Paper claim: >92% efficiency at 256 nodes.
 
 (3) **autotune** — ``runtime.autotune_stream`` over re-planned bucket-size
-    candidates × pump fan-outs, minimizing simulated makespan. This is the
+    candidates, minimizing simulated makespan. This is the
     reuse-vs-balance trade made visible: coarse buckets maximize merged-
     prefix reuse (least total work, best makespan), finer buckets maximize
     efficiency; the chosen point and the best-efficiency point are both
@@ -63,25 +58,18 @@ N_SIM_RUNS = 160
 # (lock + lease bookkeeping + callback; the real rows' pump_occ is the
 # container-scale measurement of the same quantity).
 PUMP_SERVICE = 1.5e-3
-HIER_FANOUT = 16
 # The paper-scale operating point: bucket size 14 keeps per-bucket work
 # fine enough that 7,168 cores stay load-balanced (bucket 28 trades that
 # balance for deeper merged-prefix reuse — the autotune rows quantify it).
 OPERATING_BUCKET = 14
 BUCKET_CANDIDATES = (14, 28) if SMOKE else (7, 14, 28)
 
-# CI regression gate (the sched-smoke job re-reads this from the artifact):
-# hierarchical simulated efficiency at 256 nodes must stay ≥ this floor.
-EFF_FLOOR = 0.90
-
 
 def _sched_tags(sched: Dict) -> str:
-    """The per-row scheduler observables (DESIGN.md §15)."""
+    """The per-row scheduler observables."""
     return (
         f"pump_occ={sched['pump_occupancy']:.2f}"
         f"_idle={sched['worker_idle_fraction']:.2f}"
-        f"_hit={sched['locality_hit_rate']:.2f}"
-        f"_steals={sched['steals']}"
     )
 
 
@@ -133,25 +121,6 @@ def run(csv: List[str]) -> None:
             f"_{_sched_tags(stream.scheduler)}"
         )
 
-    # hierarchical scheduler over the same study: 2 sub-manager pumps,
-    # locality-aware dispatch + stealing — outputs must stay bit-identical
-    t0 = time.perf_counter()
-    sessions0 = Manager.sessions_started
-    hier = execute_study(
-        plan, tiles, cluster=ClusterSpec(n_workers=4), hierarchy="fanout=2,block=2"
-    )
-    dt = time.perf_counter() - t0
-    assert Manager.sessions_started - sessions0 == 1
-    assert hier.scheduler["mode"] == "hierarchical"
-    check_identical(hier)
-    csv.append(
-        f"streaming_real_hier_workers4_fanout2,{dt*1e6/n_tiles:.0f},"
-        f"throughput={hier.throughput:.2f}tiles_s"
-        f"_eff={hier.parallel_efficiency:.2f}"
-        f"_speedup_vs_seq={t_seq/max(dt,1e-9):.2f}x_sessions=1"
-        f"_{_sched_tags(hier.scheduler)}"
-    )
-
     # ---------------- (2) paper-scale streaming simulation ---------------
     mh = 64 if SMOKE else 128
     costs = measure_task_costs(mh, mh)
@@ -183,13 +152,10 @@ def run(csv: List[str]) -> None:
             f"eff={sim.parallel_efficiency:.3f}"
             f"_tput={sim.throughput:.2f}tiles_s"
             f"_pump_occ={sim.pump_occupancy:.2f}"
-            f"_idle={sim.worker_idle_fraction:.2f}"
-            f"_hit={sim.locality_hit_rate:.2f}"
-            f"_steals={sim.steals}{extra}"
+            f"_idle={sim.worker_idle_fraction:.2f}{extra}"
         )
 
     nodes_list = (32, 256) if SMOKE else (32, 64, 128, 256)
-    hier_eff_256 = 0.0
     for nodes in nodes_list:
         flat = simulate_stream(
             op_costs, N_TILES_PAPER, n_nodes=nodes, seed=0,
@@ -203,42 +169,19 @@ def run(csv: List[str]) -> None:
             f"streaming_sim_nodes{nodes}_flat", flat,
             extra=f"_vs_barrier={bar.makespan/max(flat.makespan,1e-12):.2f}x",
         )
-        hier = simulate_stream(
-            op_costs, N_TILES_PAPER, n_nodes=nodes, seed=0,
-            pump_service=PUMP_SERVICE, fanout=HIER_FANOUT, locality=True,
-        )
-        sim_row(
-            f"streaming_sim_nodes{nodes}_hier", hier,
-            extra=f"_fanout={hier.fanout}"
-            f"_vs_flat={flat.makespan/max(hier.makespan,1e-12):.2f}x",
-        )
-        if nodes == 256:
-            hier_eff_256 = hier.parallel_efficiency
 
-    # ---------------- (3) autotune bucket size × fan-out -----------------
+    # ---------------- (3) autotune bucket size --------------------------
     tuned = autotune_stream(
         costs_by_bucket, N_TILES_PAPER, n_nodes=256,
-        pump_service=PUMP_SERVICE, locality=True, seed=0,
+        pump_service=PUMP_SERVICE, seed=0,
     )
     sim_row(
-        f"streaming_autotune_bucket{tuned.bucket_size}_fanout{tuned.fanout}",
+        f"streaming_autotune_bucket{tuned.bucket_size}",
         tuned.sim,
         extra=f"_candidates={len(tuned.table)}",
     )
-    best_eff = max(tuned.table, key=lambda row: row[3])
+    best_eff = max(tuned.table, key=lambda row: row[2])
     csv.append(
-        f"streaming_autotune_best_eff,{best_eff[2]*1e6:.0f},"
-        f"bucket={best_eff[0]}_fanout={best_eff[1]}_eff={best_eff[3]:.3f}"
-    )
-
-    # the recorded regression gate: CI fails if the hierarchical 256-node
-    # efficiency ever drops below the floor written into this artifact
-    assert hier_eff_256 >= EFF_FLOOR, (
-        f"hierarchical 256-node efficiency {hier_eff_256:.3f} fell below "
-        f"the {EFF_FLOOR} floor"
-    )
-    csv.append(
-        f"streaming_sim_floor,{EFF_FLOOR*1e6:.0f},"
-        f"floor={EFF_FLOOR:.2f}_achieved={hier_eff_256:.3f}_nodes=256"
-        f"_paper=0.92"
+        f"streaming_autotune_best_eff,{best_eff[1]*1e6:.0f},"
+        f"bucket={best_eff[0]}_eff={best_eff[2]:.3f}"
     )

@@ -23,11 +23,6 @@ Usage (README-level):
     # (DESIGN.md §14) ride the spec: --backend 'process[none]' replays the
     # pre-fast-path wire, 'process[-shm]' drops one mechanism, etc.
 
-    # --hierarchy 4 splits the Manager into 4 sub-manager pumps with
-    # locality-aware dispatch and work stealing (DESIGN.md §15); results
-    # stay bit-identical to the flat scheduler. 'auto' sizes the fan-out
-    # from the pool; 'fanout=4,-steal' tunes individual features.
-
     # Adaptive mode (DESIGN.md §11): a multi-round MOAT -> prune -> VBD ->
     # refine study driven by repro.study.StudyDriver — one persistent
     # Manager session and result store across rounds, each round planning
@@ -86,7 +81,6 @@ def run_adaptive(args) -> None:
         n_workers=args.workers,
         seed=3,
         backend=args.backend,
-        hierarchy=args.hierarchy,
     )
     dispatch = ", ".join(f"{k}={v}" for k, v in out["dispatch_counts"].items())
     print(
@@ -172,11 +166,6 @@ def main() -> None:
                          "e.g. 'socket[store=obj:/data/sa]'. Fast-path "
                          "flags select per DESIGN.md §14, e.g. "
                          "'process[none]' or 'process[-shm]'")
-    ap.add_argument("--hierarchy", default=None,
-                    help="scheduler topology for the Manager session "
-                         "(DESIGN.md §15): 'flat' (default, one pump), an "
-                         "integer fan-out, 'auto', or a spec string like "
-                         "'fanout=4,-steal,block=16'")
     args = ap.parse_args()
     if args.backend != "thread" and not args.backend.startswith(
         ("process", "socket")
@@ -242,8 +231,7 @@ def main() -> None:
 
     t0 = time.perf_counter()
     try:
-        stream = execute_study(plan, tiles, cluster=cluster, backend=backend,
-                               hierarchy=args.hierarchy)
+        stream = execute_study(plan, tiles, cluster=cluster, backend=backend)
         t_hybrid = time.perf_counter() - t0  # before cleanup: timing the
     finally:                                 # study, not the rmtree
         if backend is not None:
@@ -260,13 +248,6 @@ def main() -> None:
           f"[{stream.backend} backend, {stream.throughput:.2f} tiles/s, "
           f"eff={stream.parallel_efficiency:.2f}, "
           f"{stream.manager_sessions} Manager session]")
-    sched = stream.scheduler
-    if sched.get("fanout", 1) > 1:
-        print(f"scheduler [{sched['mode']} fanout={sched['fanout']}]: "
-              f"{sched['steals']} steals ({sched['steal_items']} items), "
-              f"locality hit-rate {sched['locality_hit_rate']:.2f}, "
-              f"pump occupancy {sched['pump_occupancy']:.2f}, "
-              f"mean worker idle {sched['worker_idle_fraction']:.2f}")
     corr = correlation_indices(SPACE, sets, mean_scores)
     print("top parameters by |spearman|:")
     for name, v in sorted(corr.items(), key=lambda kv: -abs(kv[1]["spearman"]))[:8]:

@@ -1,7 +1,7 @@
 """Pass 1b — lock-acquisition ordering.
 
 Builds the directed lock-acquisition graph across every analyzed class
-(Manager/_SubPump/ResultCache/SharedStore/SocketBackend/…): an edge
+(Manager/ResultCache/SharedStore/SocketBackend/…): an edge
 ``A -> B`` means some code path acquires ``B`` while holding ``A``, either
 lexically (``with A: ... with B:``) or one call level deep (``with A:
 self.x.m()`` where ``m`` acquires ``B`` — ``self.x``'s class resolved from

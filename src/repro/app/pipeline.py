@@ -339,7 +339,6 @@ def run_study(
     n_workers: int = 1,
     memory_budget_bytes: Optional[int] = None,
     backend: Any = None,
-    hierarchy: Any = None,
 ) -> Dict[str, Any]:
     """Execute an SA study over one tile and return per-run Dice + counters.
 
@@ -370,7 +369,7 @@ def run_study(
     raw = {"raw": jnp.asarray(image)}
     backend_obj = _backend_for(backend, [image], costs)
     try:
-        result = execute_plan(plan, raw, backend=backend_obj, hierarchy=hierarchy)
+        result = execute_plan(plan, raw, backend=backend_obj)
     finally:
         _backend_cleanup(backend, backend_obj)
 
@@ -414,7 +413,6 @@ def run_dataset_study(
     n_workers: int = 2,
     memory_budget_bytes: Optional[int] = None,
     backend: Any = None,
-    hierarchy: Any = None,
 ) -> Dict[str, Any]:
     """Dataset-level SA study: many tiles streamed through ONE plan and one
     persistent Manager session (DESIGN.md §10).
@@ -445,9 +443,7 @@ def run_dataset_study(
     raws = [{"raw": jnp.asarray(im)} for im in images]
     backend_obj = _backend_for(backend, images, costs)
     try:
-        stream = execute_study(
-            plan, raws, cluster=cluster, backend=backend_obj, hierarchy=hierarchy
-        )
+        stream = execute_study(plan, raws, cluster=cluster, backend=backend_obj)
     finally:
         _backend_cleanup(backend, backend_obj)
 
@@ -503,7 +499,6 @@ def run_adaptive_study(
     store_dir: Optional[str] = None,
     sa_policy: Optional[Any] = None,
     backend: Any = None,
-    hierarchy: Any = None,
 ) -> Dict[str, Any]:
     """Adaptive MOAT → prune → VBD → refine study over tiles (DESIGN.md §11).
 
@@ -565,7 +560,6 @@ def run_adaptive_study(
         # the zero-recompute-resume guarantee (the workers' caches are
         # where the results live in spec mode)
         backend=_backend_for(backend, images, costs, store_dir=store_dir),
-        hierarchy=hierarchy,
     )
     try:
         state = driver.run(max_rounds=max_rounds)

@@ -231,7 +231,6 @@ def execute_plan(
     *,
     cluster: Optional[ClusterSpec] = None,
     backend: Any = None,
-    hierarchy: Any = None,
 ) -> StudyResult:
     """Execute a :class:`StudyPlan` on one input, returning per-run outputs.
 
@@ -240,16 +239,11 @@ def execute_plan(
     by ``run_id`` alone. This is ``execute_study`` with a one-element
     dataset — same session machinery, same cache keying, same accounting.
     ``backend`` is the session's WorkerBackend spec (default: in-process
-    Worker threads; pass a ``ProcessRpcBackend`` for RPC worker processes);
-    ``hierarchy`` is the session's scheduler topology (DESIGN.md §15 —
-    flat single pump by default, ``"fanout=N"`` for manager-of-managers).
+    Worker threads; pass a ``ProcessRpcBackend`` for RPC worker processes).
     """
     from repro.engine.streaming import execute_study  # circular at import time
 
-    stream = execute_study(
-        plan, [input_state], cluster=cluster, backend=backend,
-        hierarchy=hierarchy,
-    )
+    stream = execute_study(plan, [input_state], cluster=cluster, backend=backend)
     only = stream.per_input[0]
     return StudyResult(
         outputs=only.outputs,

@@ -95,7 +95,6 @@ def execute_study(
     cache: Optional[ResultCache] = None,
     manager: Optional[Manager] = None,
     backend: Any = None,
-    hierarchy: Any = None,
     input_keys: Optional[Sequence[Any]] = None,
     key_prefix: str = "",
     shared: bool = False,
@@ -134,16 +133,10 @@ def execute_study(
 
     ``backend`` selects the session's WorkerBackend (default: in-process
     Worker threads; mutually exclusive with ``manager``, whose own backend
-    is used). ``hierarchy`` selects the session's scheduler topology
-    (DESIGN.md §15): ``None``/"flat" keeps the single-pump Manager,
-    ``"fanout=N"`` (or an int, ``"auto"``, or a
-    :class:`~repro.runtime.hierarchy.HierarchySpec`) splits dispatch
-    across N sub-manager pumps with locality-aware routing and work
-    stealing — outputs stay bit-identical, only placement changes; also
-    mutually exclusive with ``manager``. The session's scheduler counters
-    (pump occupancy, steals, locality hit-rate) are returned in
-    ``StudyStreamResult.scheduler``. With a **spec-capable** backend (``ProcessRpcBackend``) the
-    executor ships no closures: it broadcasts the plan's ``recipe`` (the
+    is used). The session's scheduler counters (pump occupancy, worker
+    idle fraction) are returned in ``StudyStreamResult.scheduler``. With
+    a **spec-capable** backend (``ProcessRpcBackend``) the executor ships
+    no closures: it broadcasts the plan's ``recipe`` (the
     picklable planning arguments — workers rebuild the plan against their
     own ``build()`` context) and each WorkItem carries a ``("bucket",
     plan_id, input, stage, bucket)`` spec. Workers resolve stage inputs
@@ -191,7 +184,6 @@ def execute_study(
             heartbeat_timeout=cluster.heartbeat_timeout,
             straggler_factor=cluster.straggler_factor,
             enable_backup_tasks=cluster.enable_backup_tasks,
-            hierarchy=hierarchy,
         )
     else:
         owns_manager = False
@@ -200,11 +192,6 @@ def execute_study(
             raise ValueError(
                 "pass backend= when the executor owns the session; an "
                 "external Manager already carries its own backend"
-            )
-        if hierarchy is not None:
-            raise ValueError(
-                "pass hierarchy= when the executor owns the session; an "
-                "external Manager already carries its own hierarchy"
             )
         if not mgr.is_running:
             raise RuntimeError("external Manager session must be started")
@@ -266,10 +253,6 @@ def execute_study(
                         # closure; workers hold the same plan (rebuilt from
                         # the recipe) and resolve src from the shared store
                         spec=("bucket", plan_id, i, si, bi) if spec_mode else None,
-                        # reuse-tree prefix for locality-aware hierarchical
-                        # dispatch: input first (stage s+1 chases stage s's
-                        # worker), then the bucket's trie scope
-                        path=(f"{key_prefix}{input_keys[i]}",) + bucket.cache_scope,
                         # stages differ in cost by orders of magnitude, and
                         # each one's first bucket compiles: judge stragglers
                         # per stage

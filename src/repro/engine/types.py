@@ -283,9 +283,8 @@ class StudyStreamResult:
     # was handed during this study (delta of Manager.dispatch_counts)
     backend: str = "thread"
     dispatch_counts: Dict[str, int] = dataclasses.field(default_factory=dict)
-    # Manager.scheduler_stats() snapshot at study end: hierarchy mode and
-    # fanout, steal/locality counters, pump occupancy, per-worker busy
-    # seconds and mean idle fraction (DESIGN.md §15)
+    # Manager.scheduler_stats() snapshot at study end: pump occupancy,
+    # per-worker busy seconds and mean idle fraction
     scheduler: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property

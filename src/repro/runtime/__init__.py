@@ -4,10 +4,6 @@ processes), hierarchical storage, fault tolerance (heartbeats/retry/backup
 tasks), elastic scaling, and the paper-scale cluster simulator."""
 
 from repro.runtime.fairshare import FairQueue, TaskCancelled  # noqa: F401
-from repro.runtime.hierarchy import (  # noqa: F401
-    HierarchySpec,
-    parse_hierarchy,
-)
 from repro.runtime.manager import Manager, WorkItem, run_study_distributed  # noqa: F401
 from repro.runtime.net import (  # noqa: F401
     SocketBackend,

@@ -27,7 +27,7 @@ the deque it replaces — the single-study schedules, and therefore their
 outputs, are unchanged byte for byte.
 
 All mutation happens under the owning Manager's lock (the instance has no
-lock of its own), mirroring how the hierarchical sub-queues are guarded.
+lock of its own).
 """
 
 from __future__ import annotations

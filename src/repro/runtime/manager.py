@@ -41,21 +41,6 @@ under the same lock that records the result — so a raced straggler backup
 can never double-report; the callback body runs outside the lock so it may
 re-enter ``submit`` (how the streaming executor chains per-input stage
 edges).
-
-**Hierarchical scheduling** (DESIGN.md §15): at paper scale (256 nodes ×
-28 cores) a single pump thread is the global serialization point, so
-``Manager(hierarchy=...)`` splits dispatch across a manager-of-managers:
-the leader pump keeps completions, expiry, liveness and settlement (the
-bookkeeping that makes settlement exactly-once stays centralised — one
-lock, one attempt sequence, first-completion-wins), and delegates
-contiguous lease blocks to N *sub-manager pumps*, each owning a shard of
-the WorkerBackend pool. Routing is locality-aware — work is sent to the
-sub-manager/worker already holding the longest reuse-tree prefix, tracked
-in a per-worker affinity map fed by Completion records — and idle pumps
-steal the tail half of the most loaded peer's queue. Items move between
-queues only under the Manager lock and leases are still minted centrally,
-so a stolen item can never settle twice. ``hierarchy=None`` (the default)
-keeps the flat single-pump Manager byte-for-byte.
 """
 
 from __future__ import annotations
@@ -68,12 +53,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from repro.runtime.fairshare import FairQueue, TaskCancelled
-from repro.runtime.hierarchy import (
-    HierarchySpec,
-    best_affinity,
-    parse_hierarchy,
-    path_lcp,
-)
 from repro.runtime.transport import (
     Completion,
     Lease,
@@ -84,10 +63,6 @@ from repro.runtime.transport import (
 from repro.tracing import span
 
 __all__ = ["WorkItem", "Manager", "TaskCancelled", "run_study_distributed"]
-
-# How many queue-head items a sub-pump scans for the best affinity match
-# before falling back to FIFO — bounds locality search per dispatch.
-_AFFINITY_WINDOW = 8
 
 # How long the pump blocks per completion poll; bounds the latency of
 # straggler/heartbeat detection while the system is idle.
@@ -126,10 +101,6 @@ class WorkItem:
     # ids never collide across lifecycles) and the retry budget is
     # measured from this base instead of zero.
     attempt_base: int = 0
-    # Reuse-tree prefix of this item (e.g. (input_key, stage, group)): the
-    # hierarchical scheduler routes it toward the sub-manager/worker whose
-    # affinity shares the longest common prefix. None opts out of locality.
-    path: Optional[tuple] = None
     # Fair-share class (DESIGN.md §18): the dispatch queue deficit-round-
     # robins across tenants, so one tenant's backlog cannot starve another.
     # "" is the shared default class (single-study sessions stay pure FIFO).
@@ -153,40 +124,11 @@ class WorkItem:
 
     def next_attempt(self) -> "WorkItem":
         """A queue entry for another attempt of this item (retry, expiry
-        re-enqueue or backup clone): same work, routing and class, no lease
+        re-enqueue or backup clone): same work and class, no lease
         state and no callback (callbacks live in the Manager's table)."""
         return WorkItem(key=self.key, fn=self.fn, spec=self.spec,
-                        attempt_base=self.attempt_base, path=self.path,
-                        tenant=self.tenant, priority=self.priority,
-                        kind=self.kind)
-
-
-class _SubPump:
-    """One sub-manager pump: a dispatch thread owning a shard of the
-    worker pool and a local queue of UNLEASED WorkItems. All queue
-    mutation happens under the owning Manager's lock; leases are minted
-    by the Manager's central bookkeeping at offer time."""
-
-    __slots__ = (
-        "idx", "worker_ids", "queue", "dispatched", "steals",
-        "stolen_items", "busy_seconds", "parked_seconds", "parked_since",
-        "thread", "dead",
-    )
-
-    def __init__(self, idx: int, worker_ids) -> None:
-        self.idx = idx
-        self.worker_ids = frozenset(worker_ids)
-        self.queue: "collections.deque[WorkItem]" = collections.deque()
-        self.dispatched = 0
-        self.steals = 0        # times this pump stole a block
-        self.stolen_items = 0  # items it acquired by stealing
-        self.busy_seconds = 0.0
-        self.parked_seconds = 0.0  # time parked on the Manager condvar
-        # park-in-progress start time, so stats taken MID-park still see
-        # the elapsed idle (folded into parked_seconds when the park ends)
-        self.parked_since: Optional[float] = None
-        self.thread: Optional[threading.Thread] = None
-        self.dead = False
+                        attempt_base=self.attempt_base, tenant=self.tenant,
+                        priority=self.priority, kind=self.kind)
 
 
 class Manager:
@@ -203,27 +145,14 @@ class Manager:
         heartbeat_timeout: float = 60.0,
         straggler_factor: float = 3.0,
         enable_backup_tasks: bool = True,
-        hierarchy: Any = None,
     ):
         self._backend = make_backend(backend)
-        self.hierarchy: HierarchySpec = parse_hierarchy(hierarchy)
-        self._hier: HierarchySpec = self.hierarchy  # resolved at start()
-        self._subs: List[_SubPump] = []
-        self._sub_stop = threading.Event()
-        self._sub_error: Optional[BaseException] = None  # guard: _lock
-        # Block-delegation cursor: the sub currently receiving the leader's
-        # contiguous block, and how many items remain in that block.
-        self._block_sub: Optional[_SubPump] = None  # guard: _lock
-        self._block_left = 0  # guard: _lock
-        # worker_id -> reuse-tree path of its last successful completion:
-        # the affinity map behind locality-aware dispatch.
-        self._affinity: Dict[int, tuple] = {}  # guard: _lock
         # worker_id -> attempt-seconds it has executed (all attempts, both
         # outcomes) — the per-worker occupancy the benchmark reports.
         self._worker_busy: Dict[int, float] = {}  # guard: _lock
         self._n_workers = 0  # guard: _lock
-        self._pump_busy = 0.0  # guard: _lock — leader-pump seconds spent doing work
-        # Idle-pool accounting (DESIGN.md §18): seconds the leader pump has
+        self._pump_busy = 0.0  # guard: _lock — pump seconds spent doing work
+        # Idle-pool accounting (DESIGN.md §18): seconds the pump has
         # spent parked on the condition variable with zero pending work, and
         # the start of an in-progress park — scheduler_stats subtracts this
         # from wall time so idle fractions stay honest across the many-job
@@ -232,10 +161,6 @@ class Manager:
         self._parked_since: Optional[float] = None  # guard: _lock
         self._session_t0: Optional[float] = None  # guard: _lock
         self._session_t1: Optional[float] = None  # guard: _lock
-        self.steals = 0  # guard: _lock
-        self.steal_items = 0  # guard: _lock
-        self.locality_hits = 0  # guard: _lock
-        self.locality_misses = 0  # guard: _lock
         self._queue: FairQueue = FairQueue()  # guard: _lock
         self._results: Dict[str, Any] = {}  # guard: _lock
         self._running: Dict[str, WorkItem] = {}  # guard: _lock
@@ -310,14 +235,12 @@ class Manager:
             return self._busy_total
 
     def scheduler_stats(self) -> Dict[str, Any]:
-        """Snapshot of the scheduler's shape and health: hierarchy mode and
-        fanout, work-stealing and locality counters, pump occupancy (the
-        fraction of session wall-time each pump spent doing scheduling
-        work — the serialization metric the hierarchy exists to fix), and
-        per-worker busy seconds / mean idle fraction. ``worker_busy_seconds``
-        sums attempt wall-times, which on an asynchronous device time the
-        launch plus any wait on PJRT's in-flight limit, not device work (see
-        :attr:`busy_seconds`)."""
+        """Snapshot of the scheduler's health: pump occupancy (the fraction
+        of active wall-time the pump spent doing scheduling work), parked
+        and active wall seconds, and per-worker busy seconds / mean idle
+        fraction. ``worker_busy_seconds`` sums attempt wall-times, which on
+        an asynchronous device time the launch plus any wait on PJRT's
+        in-flight limit, not device work (see :attr:`busy_seconds`)."""
         now = time.monotonic()
         with self._lock:
             t0 = self._session_t0
@@ -333,33 +256,12 @@ class Manager:
             # work, not how empty the hour was.
             active = max(wall - parked, 0.0)
             denom = active if active > 1e-9 else wall
-            hits, misses = self.locality_hits, self.locality_misses
             worker_busy = dict(self._worker_busy)
             n_workers = max(1, self._n_workers)
             stats: Dict[str, Any] = {
-                "mode": "hierarchical" if self._subs else "flat",
-                "fanout": len(self._subs) if self._subs else 1,
-                "steals": self.steals,
-                "steal_items": self.steal_items,
-                "locality_hits": hits,
-                "locality_misses": misses,
-                "locality_hit_rate": (
-                    hits / (hits + misses) if (hits + misses) else 0.0
-                ),
                 "pump_occupancy": self._pump_busy / denom if denom else 0.0,
                 "pump_parked_seconds": parked,
                 "active_wall_seconds": active,
-                "sub_occupancy": [
-                    s.busy_seconds / denom if denom else 0.0
-                    for s in self._subs
-                ],
-                "sub_parked_seconds": [
-                    s.parked_seconds
-                    + (now - s.parked_since if s.parked_since is not None else 0.0)
-                    for s in self._subs
-                ],
-                "dispatched_per_sub": [s.dispatched for s in self._subs],
-                "steals_per_sub": [s.steals for s in self._subs],
                 "worker_busy_seconds": worker_busy,
                 "worker_idle_fraction": (
                     min(
@@ -418,30 +320,11 @@ class Manager:
                 self._cond.notify_all()
             raise
         Manager.sessions_started += 1
-        wids = sorted(self._backend.heartbeat_view().keys())
+        n_live = len(self._backend.heartbeat_view())
         with self._lock:
-            self._n_workers = len(wids) or max(1, n_workers)
+            self._n_workers = n_live or max(1, n_workers)
             self._session_t0 = time.monotonic()
             self._session_t1 = None
-            self._hier = self.hierarchy.resolve(self._n_workers)
-            self._sub_error = None
-            self._sub_stop = threading.Event()
-            self._subs = []
-            self._block_sub = None
-            self._block_left = 0
-            if self._hier.fanout > 1 and wids:
-                # contiguous worker-id shards, one per sub-manager pump
-                fanout = self._hier.fanout
-                n = len(wids)
-                self._subs = [
-                    _SubPump(g, wids[g * n // fanout: (g + 1) * n // fanout])
-                    for g in range(fanout)
-                ]
-        for sub in self._subs:
-            sub.thread = threading.Thread(
-                target=self._sub_pump, args=(sub,), daemon=True
-            )
-            sub.thread.start()
         self._pump_thread = threading.Thread(target=self._pump, daemon=True)
         self._pump_thread.start()
 
@@ -478,14 +361,8 @@ class Manager:
                     self._orphaned.add(lid)
                     del self._running[lid]
                 # queued duplicates (heartbeat-expiry re-enqueues racing in
-                # after forget) carry the OLD lifecycle's closure — purge
-                # every queue they may sit in (global + delegated shards)
+                # after forget) carry the OLD lifecycle's closure — purge them
                 self._queue.remove_keys({item.key})
-                for sub in self._subs:
-                    if any(it.key == item.key for it in sub.queue):
-                        sub.queue = collections.deque(
-                            it for it in sub.queue if it.key != item.key
-                        )
                 item.attempt_base = self._attempt_seq.get(item.key, 0)
             if item.key in self._results:
                 if item.shared and item.callback is not None:
@@ -561,13 +438,6 @@ class Manager:
             pump = self._pump_thread
         if pump is not None:
             pump.join()
-        self._sub_stop.set()
-        with self._cond:
-            self._cond.notify_all()  # unpark sub-pumps so they see the stop
-        for sub in self._subs:
-            if sub.thread is not None:
-                sub.thread.join()
-                sub.thread = None
         self._backend.shutdown()
         with self._cond:
             if self._session_t0 is not None and self._session_t1 is None:
@@ -600,11 +470,6 @@ class Manager:
             if not keyset:
                 return
             self._queue.remove_keys(keyset)
-            for sub in self._subs:
-                if any(it.key in keyset for it in sub.queue):
-                    sub.queue = collections.deque(
-                        it for it in sub.queue if it.key not in keyset
-                    )
             leased = {it.key for it in self._running.values()}
             # Keys with an outstanding ORPHANED lease are held too: their
             # drop-marker carries a lease id minted from the key's attempt
@@ -622,7 +487,7 @@ class Manager:
 
     def cancel(self, keys) -> List[str]:
         """Revoke submitted-but-unsettled keys (DESIGN.md §18): queued
-        work is purged from every queue (global + delegated shards), live
+        work is purged from the queue, live
         leases are poisoned (their ids join the orphan set, so the
         worker's eventual completion is dropped on arrival — the worker
         itself is not interrupted mid-task), and each revoked key settles
@@ -647,11 +512,6 @@ class Manager:
             if not live:
                 return cancelled
             self._queue.remove_keys(live)
-            for sub in self._subs:
-                if any(it.key in live for it in sub.queue):
-                    sub.queue = collections.deque(
-                        it for it in sub.queue if it.key not in live
-                    )
             for lid, it in list(self._running.items()):
                 if it.key in live:
                     self._orphaned.add(lid)
@@ -712,9 +572,8 @@ class Manager:
 
     def _lease_locked(self, item: WorkItem) -> None:
         """Mint a lease for ``item`` under the Manager lock. Attempt
-        numbers are issued centrally — here and ONLY here — so concurrent
-        attempts of one key (original + backup, or a stolen re-dispatch)
-        always hold distinct leases, whichever pump leases them."""
+        numbers are issued here and ONLY here, so concurrent attempts of one
+        key (original + backup clone) always hold distinct leases."""
         item.started_at = time.monotonic()
         item.attempts = self._attempt_seq.get(item.key, 0) + 1
         self._attempt_seq[item.key] = item.attempts
@@ -722,134 +581,6 @@ class Manager:
         self.tenant_dispatch[item.tenant] = (
             self.tenant_dispatch.get(item.tenant, 0) + 1
         )
-
-    # -- hierarchical scheduling (leader + sub-manager pumps) ----------
-    def _route_locked(self, item: WorkItem) -> Optional[_SubPump]:
-        """Pick the sub-manager to delegate ``item`` to: the shard whose
-        workers hold the longest reuse-tree prefix of ``item.path`` wins
-        (locality); otherwise the leader fills contiguous blocks of
-        ``block_size`` into the currently-shortest queue."""
-        subs = [s for s in self._subs if not s.dead]
-        if not subs:
-            return None
-        if self._hier.locality and item.path:
-            best: Optional[_SubPump] = None
-            best_l = 0
-            for s in subs:
-                l = best_affinity(
-                    item.path, [self._affinity.get(w) for w in s.worker_ids]
-                )
-                if l > best_l:
-                    best, best_l = s, l
-            if best is not None:
-                return best
-        if (
-            self._block_left <= 0
-            or self._block_sub is None
-            or self._block_sub.dead
-        ):
-            self._block_sub = min(subs, key=lambda s: len(s.queue))
-            self._block_left = self._hier.block_size
-        self._block_left -= 1
-        return self._block_sub
-
-    def _distribute_locked(self) -> int:
-        """Leader-side delegation: move everything queued globally into the
-        sub-manager queues (locality first, contiguous blocks otherwise).
-        With nothing queued anywhere, fall back to straggler backup-task
-        cloning — the clone is delegated like any other item, and a queued
-        clone blocks further cloning of the same key (the all-queues-empty
-        guard) until it is leased."""
-        moved = 0
-        while self._queue:
-            item = self._queue.popleft()
-            sub = self._route_locked(item)
-            if sub is None:  # every sub-pump died; leader will fail over
-                self._queue.appendleft(item)
-                return moved
-            sub.queue.append(item)
-            moved += 1
-        if moved == 0 and not any(s.queue for s in self._subs):
-            clone = self._maybe_backup_locked()
-            if clone is not None:
-                sub = self._route_locked(clone)
-                if sub is not None:
-                    sub.queue.append(clone)
-                    moved += 1
-        return moved
-
-    def _steal_locked(self, thief: _SubPump) -> int:
-        """Work stealing: an idle pump takes the tail half of the most
-        loaded peer's queue (relative order preserved). Items are unleased
-        while queued, and the move happens under the Manager lock, so
-        exactly-once settlement is untouched — the thief simply becomes
-        the pump that eventually mints the lease."""
-        victim: Optional[_SubPump] = None
-        for s in self._subs:
-            if s is thief or s.dead:
-                continue
-            if victim is None or len(s.queue) > len(victim.queue):
-                victim = s
-        if victim is None or len(victim.queue) < max(2, self._hier.steal_min):
-            return 0
-        n = len(victim.queue) // 2
-        stolen = [victim.queue.pop() for _ in range(n)]
-        stolen.reverse()
-        thief.queue.extend(stolen)
-        thief.steals += 1
-        thief.stolen_items += n
-        self.steals += 1
-        self.steal_items += n
-        return n
-
-    def _next_sub_locked(
-        self, sub: _SubPump, worker_id: Optional[int] = None
-    ) -> Optional[WorkItem]:
-        """Dequeue-and-lease from a sub-manager's queue. With a target
-        worker and locality enabled, the first ``_AFFINITY_WINDOW`` items
-        are scanned for the longest prefix match against that worker's
-        affinity path; otherwise FIFO. Locality hits/misses are tallied
-        here — a hit means the chosen placement shares ≥1 path segment
-        with the worker's (or, for shard-batched dispatch, the shard's)
-        last completed work."""
-        while sub.queue:
-            idx = 0
-            best_l = 0
-            if self._hier.locality and worker_id is not None:
-                aff = self._affinity.get(worker_id)
-                if aff:
-                    window = min(len(sub.queue), _AFFINITY_WINDOW)
-                    for j in range(window):
-                        it = sub.queue[j]
-                        l = path_lcp(it.path, aff)
-                        if l > best_l:
-                            best_l, idx = l, j
-            if idx:
-                sub.queue.rotate(-idx)
-                item = sub.queue.popleft()
-                sub.queue.rotate(idx)
-            else:
-                item = sub.queue.popleft()
-            if item.key in self._results:
-                continue
-            if self._hier.locality and item.path is not None:
-                if worker_id is not None:
-                    hit = best_l >= 1
-                else:
-                    hit = (
-                        best_affinity(
-                            item.path,
-                            [self._affinity.get(w) for w in sub.worker_ids],
-                        )
-                        >= 1
-                    )
-                if hit:
-                    self.locality_hits += 1
-                else:
-                    self.locality_misses += 1
-            self._lease_locked(item)
-            return item
-        return None
 
     def _unlease_locked(self, item: WorkItem) -> None:
         """Revert ``_next_locked`` for a lease no worker accepted (a slot
@@ -1000,169 +731,6 @@ class Manager:
         self.backups_launched += 1
         return worst.next_attempt()
 
-    def _sub_pump(self, sub: _SubPump) -> None:
-        """Sub-manager pump thread wrapper: a crashed pump returns its
-        unleased work to the leader (which redistributes to surviving
-        pumps); when the LAST pump dies the leader fails the session's
-        pending work loudly instead of letting drain() hang."""
-        try:
-            self._sub_pump_loop(sub)
-        except BaseException as err:  # noqa: BLE001 — fail over to leader
-            with self._cond:
-                sub.dead = True
-                while sub.queue:
-                    self._queue.append(sub.queue.popleft())
-                if all(s.dead for s in self._subs):
-                    self._sub_error = err
-                self._cond.notify_all()
-
-    def _sub_pump_loop(self, sub: _SubPump) -> None:
-        backend = self._backend
-        offer_to = getattr(backend, "offer_to", None)
-        offer_batch = getattr(backend, "offer_batch", None)
-        slots = max(1, int(getattr(backend, "slots_per_worker", 1)))
-        while not self._sub_stop.is_set():
-            # Same idle-pool parking as the leader: with zero pending work
-            # the shard pump blocks on the Manager condvar instead of
-            # spinning on heartbeat snapshots. Woken by submit()/close()/
-            # the leader's delegation notify; state changes and sub-errors
-            # break the predicate so shutdown is never missed.
-            with self._cond:
-                if (
-                    self._state == _RUNNING
-                    and self._sub_error is None
-                    and not self._sub_stop.is_set()
-                    and not self._pending
-                    and not self._running
-                    and not self._queue
-                    and not any(s.queue for s in self._subs)
-                ):
-                    t_park = time.monotonic()
-                    sub.parked_since = t_park
-                    self._cond.wait()
-                    sub.parked_seconds += time.monotonic() - t_park
-                    sub.parked_since = None
-                    continue
-            view = backend.heartbeat_view()
-            alive = {
-                wid: st
-                for wid, st in view.items()
-                if wid in sub.worker_ids and st.alive
-            }
-            if not alive and all(wid in view for wid in sub.worker_ids):
-                # the WHOLE shard died (worker death is permanent): this
-                # pump can never dispatch again, and peers only steal from
-                # queues ≥ steal_min — a single queued item would strand.
-                # Retire cleanly: return unleased work to the leader, which
-                # redistributes to surviving shards (or, with the pool
-                # fully dead, fails pending loudly via its dead-pool path).
-                with self._cond:
-                    sub.dead = True
-                    while sub.queue:
-                        self._queue.append(sub.queue.popleft())
-                    self._cond.notify_all()
-                return
-            free = sum(
-                max(0, slots - len(st.inflight)) for st in alive.values()
-            )
-            if free <= 0:
-                # all shard slots busy: wait a tick (woken early by any
-                # settle/submit notify) instead of a blind sleep
-                with self._cond:
-                    self._cond.wait(_IDLE_TICK)
-                continue
-            if self._hier.steal:
-                with self._cond:
-                    if not sub.queue:
-                        self._steal_locked(sub)
-            t0 = time.monotonic()
-            if offer_batch is not None:
-                did = self._sub_dispatch_batched(sub, offer_batch, free)
-            else:
-                did = self._sub_dispatch_targeted(
-                    sub, alive, slots, offer_to
-                )
-            if did:
-                sub.busy_seconds += time.monotonic() - t0
-            else:
-                with self._cond:
-                    self._cond.wait(_IDLE_TICK)
-
-    def _sub_dispatch_targeted(
-        self, sub: _SubPump, alive: Dict[int, WorkerStatus], slots: int,
-        offer_to,
-    ) -> int:
-        """Per-worker targeted dispatch (thread backend): each free worker
-        in the shard gets the queued item with the longest affinity-prefix
-        match. Falls back to untargeted ``offer`` if the backend cannot
-        address workers (shard ownership then degrades to advisory)."""
-        dispatched = 0
-        for wid, st in alive.items():
-            if len(st.inflight) >= slots:
-                continue
-            with self._cond:
-                item = self._next_sub_locked(sub, worker_id=wid)
-            if item is None:
-                break
-            lease = Lease(
-                key=item.key, attempt=item.attempts, fn=item.fn,
-                spec=item.spec,
-            )
-            ok = (
-                offer_to(lease, wid)
-                if offer_to is not None
-                else self._backend.offer(lease)
-            )
-            if ok:
-                dispatched += 1
-                with self._cond:
-                    sub.dispatched += 1
-                    self.dispatch_counts[self.backend_name] = (
-                        self.dispatch_counts.get(self.backend_name, 0) + 1
-                    )
-            else:  # slot vanished since the snapshot (worker death)
-                with self._cond:
-                    self._unlease_locked(item)
-                break
-        return dispatched
-
-    def _sub_dispatch_batched(self, sub: _SubPump, offer_batch, free: int) -> int:
-        """Shard-restricted batched dispatch (process backend): lease up
-        to ``free`` items and hand them to the backend restricted to this
-        sub-manager's workers. Shards partition the pool, so concurrent
-        sub-pumps touch disjoint worker handles."""
-        batch: List[WorkItem] = []
-        with self._cond:
-            while len(batch) < free:
-                item = self._next_sub_locked(sub)
-                if item is None:
-                    break
-                batch.append(item)
-        if not batch:
-            return 0
-        leases = [
-            Lease(key=it.key, attempt=it.attempts, fn=it.fn, spec=it.spec)
-            for it in batch
-        ]
-        try:
-            rejected = {
-                lease.lease_id
-                for lease in offer_batch(leases, worker_ids=sub.worker_ids)
-            }
-        except TypeError:  # backend without shard targeting: untargeted
-            rejected = {lease.lease_id for lease in offer_batch(leases)}
-        accepted = len(batch) - len(rejected)
-        with self._cond:
-            if accepted:
-                sub.dispatched += accepted
-                self.dispatch_counts[self.backend_name] = (
-                    self.dispatch_counts.get(self.backend_name, 0) + accepted
-                )
-            for it in reversed(batch):
-                if f"{it.key}#{it.attempts}" in rejected:
-                    self._unlease_locked(it)
-        return accepted
-
     def _settle(
         self, key: str, attempt: int, value: Any, duration: Optional[float]
     ) -> None:
@@ -1212,16 +780,10 @@ class Manager:
                 self._drain_deferred_locked(comp.key)
                 return
             item = self._running.get(comp.lease_id)
-            if comp.worker_id is not None:
-                if comp.duration:
-                    self._worker_busy[comp.worker_id] = (
-                        self._worker_busy.get(comp.worker_id, 0.0)
-                        + comp.duration
-                    )
-                if comp.ok and item is not None and item.path is not None:
-                    # feed the affinity map: this worker now holds the
-                    # reuse-tree prefix of the work it just finished
-                    self._affinity[comp.worker_id] = item.path
+            if comp.worker_id is not None and comp.duration:
+                self._worker_busy[comp.worker_id] = (
+                    self._worker_busy.get(comp.worker_id, 0.0) + comp.duration
+                )
         if comp.ok:
             self._settle(comp.key, comp.attempt, comp.value, comp.duration)
             return
@@ -1263,17 +825,12 @@ class Manager:
         try:
             self._pump_loop()
         except BaseException as pump_err:  # noqa: BLE001 — fail pending work
-            self._sub_stop.set()
             with self._cond:
-                delegated = [it for s in self._subs for it in s.queue]
                 stranded = {
                     it.key
-                    for it in list(self._queue) + delegated
-                    + list(self._running.values())
+                    for it in list(self._queue) + list(self._running.values())
                 } | set(self._pending)
                 self._queue.clear()
-                for s in self._subs:
-                    s.queue.clear()
                 self._running.clear()
             for key in stranded:
                 self._settle(
@@ -1286,7 +843,6 @@ class Manager:
                 self._cond.notify_all()
             raise
         finally:
-            self._sub_stop.set()
             with self._cond:
                 if self._session_t1 is None:
                     self._session_t1 = time.monotonic()
@@ -1295,11 +851,10 @@ class Manager:
                         time.monotonic() - self._parked_since
                     )
                     self._parked_since = None
-                self._cond.notify_all()  # unpark sub-pumps: stop is set
+                self._cond.notify_all()
 
     def _pump_loop(self) -> None:
         backend = self._backend
-        hier = bool(self._subs)
         while True:
             # Idle-pool parking (DESIGN.md §18): with zero pending work —
             # nothing queued anywhere, no leases in flight — a long-lived
@@ -1313,12 +868,10 @@ class Manager:
             with self._cond:
                 if (
                     self._state == _RUNNING
-                    and self._sub_error is None
                     and not self._pending
                     and not self._running
                     and not self._orphaned
                     and not self._queue
-                    and not any(s.queue for s in self._subs)
                 ):
                     if self._parked_since is None:
                         self._parked_since = time.monotonic()
@@ -1337,7 +890,7 @@ class Manager:
                     self._parked_since = None
             comps = backend.poll_completions(0.0 if just_woke else _IDLE_TICK)
             t_work = time.monotonic()
-            self._pump_tick(backend, comps, hier)
+            self._pump_tick(backend, comps)
             with self._cond:
                 self._pump_busy += time.monotonic() - t_work
                 if (
@@ -1345,14 +898,12 @@ class Manager:
                     and not self._pending
                     and not self._running
                     and not self._queue
-                    and not any(s.queue for s in self._subs)
                 ):
                     return
 
-    def _pump_tick(self, backend, comps: List[Completion], hier: bool) -> None:
+    def _pump_tick(self, backend, comps: List[Completion]) -> None:
         """One pump tick's work: settle ``comps``, expire dead and stale
-        leases, then offer leases to free workers (or, hierarchical,
-        delegate them to the sub-pumps). The tick runs inside a span
+        leases, then offer leases to free workers. The tick runs inside a span
         ``sa/dispatch`` (``completions``, ``leases``), opened at its first
         completion or else at its first lease; a tick that settles no
         completion and mints no lease records none."""
@@ -1374,12 +925,6 @@ class Manager:
             view = backend.heartbeat_view()
             to_settle: List = []
             with self._cond:
-                if self._sub_error is not None:
-                    # every sub-manager pump died: nothing can dispatch —
-                    # escalate through the pump-failure path (fail pending)
-                    raise RuntimeError(
-                        "all sub-manager pumps failed"
-                    ) from self._sub_error
                 self._expire_dead_locked(view, to_settle)
                 self._expire_heartbeats_locked(
                     view
@@ -1390,10 +935,8 @@ class Manager:
                     # the whole pool is gone (every worker process died):
                     # nothing can ever complete — fail what's left instead
                     # of spinning forever
-                    delegated = [it for s in self._subs for it in s.queue]
                     for item in (
-                        list(self._queue) + delegated
-                        + list(self._running.values())
+                        list(self._queue) + list(self._running.values())
                     ):
                         if item.key not in self._results:
                             to_settle.append(
@@ -1407,57 +950,46 @@ class Manager:
                                 )
                             )
                     self._queue.clear()
-                    for s in self._subs:
-                        s.queue.clear()
                     self._running.clear()
             for key, attempt, err in to_settle:
                 self._settle(key, attempt, err, None)
-            if hier:
-                # manager-of-managers: the leader only delegates; the
-                # sub-pumps own demand-driven dispatch for their shards
-                # (parked sub-pumps are woken when items land in shards)
-                with self._cond:
-                    leases = self._distribute_locked()
-                    if leases:
-                        self._cond.notify_all()
+            # demand-driven dispatch: free slots = per-worker queue
+            # depth (slots_per_worker > 1 when the backend batches
+            # frames — a worker holds a small backlog so it never
+            # idles between round trips; 1 for the historical
+            # one-lease-per-worker)
+            slots = max(1, int(getattr(backend, "slots_per_worker", 1)))
+            free = sum(
+                max(0, slots - len(st.inflight))
+                for st in view.values()
+                if st.alive
+            )
+            offer_batch = getattr(backend, "offer_batch", None)
+            if offer_batch is not None:
+                leases = self._dispatch_batched(offer_batch, free, open_tick)
             else:
-                # demand-driven dispatch: free slots = per-worker queue
-                # depth (slots_per_worker > 1 when the backend batches
-                # frames — a worker holds a small backlog so it never
-                # idles between round trips; 1 for the historical
-                # one-lease-per-worker)
-                slots = max(1, int(getattr(backend, "slots_per_worker", 1)))
-                free = sum(
-                    max(0, slots - len(st.inflight))
-                    for st in view.values()
-                    if st.alive
-                )
-                offer_batch = getattr(backend, "offer_batch", None)
-                if offer_batch is not None:
-                    leases = self._dispatch_batched(offer_batch, free, open_tick)
-                else:
-                    while free > 0:
+                while free > 0:
+                    with self._cond:
+                        item = self._next_locked()
+                    if item is None:
+                        break
+                    open_tick()
+                    lease = Lease(
+                        key=item.key, attempt=item.attempts, fn=item.fn,
+                        spec=item.spec,
+                    )
+                    if backend.offer(lease):
                         with self._cond:
-                            item = self._next_locked()
-                        if item is None:
-                            break
-                        open_tick()
-                        lease = Lease(
-                            key=item.key, attempt=item.attempts, fn=item.fn,
-                            spec=item.spec,
-                        )
-                        if backend.offer(lease):
-                            with self._cond:
-                                self.dispatch_counts[self.backend_name] = (
-                                    self.dispatch_counts.get(self.backend_name, 0)
-                                    + 1
-                                )
-                            free -= 1
-                            leases += 1
-                        else:  # slot vanished since snapshot (worker death)
-                            with self._cond:
-                                self._unlease_locked(item)
-                            break
+                            self.dispatch_counts[self.backend_name] = (
+                                self.dispatch_counts.get(self.backend_name, 0)
+                                + 1
+                            )
+                        free -= 1
+                        leases += 1
+                    else:  # slot vanished since snapshot (worker death)
+                        with self._cond:
+                            self._unlease_locked(item)
+                        break
             if mark is not None:
                 mark.set_metadata(leases=leases)
 

@@ -768,7 +768,7 @@ class SocketBackend:
     def offer(self, lease: Lease) -> bool:
         return not self.offer_batch([lease])
 
-    def offer_batch(self, leases: List[Lease], worker_ids=None) -> List[Lease]:
+    def offer_batch(self, leases: List[Lease]) -> List[Lease]:
         for lease in leases:
             if lease.spec is None:
                 raise TransportError(
@@ -783,7 +783,6 @@ class SocketBackend:
             ws = [
                 h for h in self._handles.values()
                 if h.alive and len(h.inflight) < slots
-                and (worker_ids is None or h.wid in worker_ids)
             ]
             ws.sort(key=lambda h: len(h.inflight))
             caps = {h.wid: slots - len(h.inflight) for h in ws}
@@ -841,9 +840,6 @@ class SocketBackend:
                 for l in batch:
                     h.inflight[l.lease_id] = l
         return rejected
-
-    def offer_to(self, lease: Lease, worker_id: int) -> bool:
-        return not self.offer_batch([lease], worker_ids={worker_id})
 
     # -- completion intake -------------------------------------------------
     def poll_completions(self, timeout: float) -> List[Completion]:

@@ -187,12 +187,9 @@ class WorkerBackend(Protocol):
     a worker's leases live mid-task, sparing them age-based expiry).
 
     Further methods are optional; the Manager discovers them by
-    ``getattr``: ``offer_batch(leases, worker_ids=None) -> rejected``
-    (batched dispatch; paired with a ``slots_per_worker`` attribute so
-    the pump sizes demand as queue depth, not just free workers;
-    ``worker_ids`` restricts a batch to a shard for the hierarchical
-    scheduler's sub-manager pumps), ``offer_to(lease, worker_id) ->
-    bool`` (locality-targeted single-worker offer, DESIGN.md §15) and
+    ``getattr``: ``offer_batch(leases) -> rejected`` (batched dispatch;
+    paired with a ``slots_per_worker`` attribute so the pump sizes demand
+    as queue depth, not just free workers) and
     ``barrier(timeout=None) -> bool`` (durability point for backends
     that acknowledge completions ahead of their disk commit;
     ``Manager.drain`` invokes it when present).
@@ -371,21 +368,6 @@ class ThreadBackend:
             else:
                 return False
         self._inboxes[wid].put(lease)
-        return True
-
-    def offer_to(self, lease: Lease, worker_id: int) -> bool:
-        """Targeted offer (hierarchical scheduling, DESIGN.md §15): hand
-        the lease to ONE specific worker — the one the affinity map says
-        already holds the longest reuse-tree prefix. False if that worker
-        is dead or busy; the caller keeps the item queued."""
-        with self._lock:
-            if not (0 <= worker_id < len(self._threads)):
-                return False
-            t = self._threads[worker_id]
-            if not t.is_alive() or self._inflight[worker_id]:
-                return False
-            self._inflight[worker_id].add(lease.lease_id)
-        self._inboxes[worker_id].put(lease)
         return True
 
     def poll_completions(self, timeout: float) -> List[Completion]:
@@ -1412,19 +1394,12 @@ class ProcessRpcBackend:
     def offer(self, lease: Lease) -> bool:
         return not self.offer_batch([lease])
 
-    def offer_batch(
-        self, leases: List[Lease], worker_ids=None
-    ) -> List[Lease]:
+    def offer_batch(self, leases: List[Lease]) -> List[Lease]:
         """Distribute a batch of leases across workers with spare queue
         depth — one ``lease_batch`` frame per worker (when batching) —
         and return the leases no worker could take (the Manager unleases
         them). Least-loaded workers are filled first, round-robin, so a
-        burst spreads instead of piling onto worker 0.
-
-        ``worker_ids`` restricts the batch to a shard of the pool — the
-        hierarchical scheduler's sub-manager pumps each own a disjoint
-        shard, so their concurrent ``offer_batch`` calls touch disjoint
-        worker handles (frame sends stay serialised by the send lock)."""
+        burst spreads instead of piling onto worker 0."""
         for lease in leases:
             if lease.spec is None:
                 raise TransportError(
@@ -1432,14 +1407,13 @@ class ProcessRpcBackend:
                     "backend cannot ship closures across the boundary"
                 )
         slots = self.slots_per_worker
-        # inflight maps are written here (sub-pump threads) and popped by
-        # the leader pump's hydration: capacity math runs under the state
-        # lock so neither side sees a map mid-mutation
+        # inflight maps are written here and popped by completion
+        # hydration: capacity math runs under the state lock so neither
+        # side sees a map mid-mutation
         with self._state_lock:
             ws = [
                 h for h in self._handles
                 if h.alive and h.proc.is_alive() and len(h.inflight) < slots
-                and (worker_ids is None or h.wid in worker_ids)
             ]
             ws.sort(key=lambda h: len(h.inflight))
             caps = {h.wid: slots - len(h.inflight) for h in ws}
@@ -1612,8 +1586,9 @@ class ProcessRpcBackend:
             alive = h.alive and h.proc.is_alive()
             if not alive:
                 h.alive = False
-            # snapshot under the state lock: a sub-pump inserting into this
-            # map mid-tuple() would raise "dict changed size during iteration"
+            # snapshot under the state lock: an offer or a completion
+            # changing this map mid-tuple() would raise "dict changed
+            # size during iteration"
             with self._state_lock:
                 inflight = tuple(h.inflight)
             view[h.wid] = WorkerStatus(

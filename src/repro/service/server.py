@@ -66,7 +66,6 @@ class StudyServer:
         input_keys: Optional[Sequence[Any]] = None,
         n_workers: int = 2,
         backend: Any = None,
-        hierarchy: Any = None,
         cluster: Optional[ClusterSpec] = None,
         cache_bytes: int = DEFAULT_CACHE_BYTES,
         default_quota: Optional[TenantQuota] = None,
@@ -89,7 +88,6 @@ class StudyServer:
             heartbeat_timeout=self.cluster.heartbeat_timeout,
             straggler_factor=self.cluster.straggler_factor,
             enable_backup_tasks=self.cluster.enable_backup_tasks,
-            hierarchy=hierarchy,
         )
         self._mgr.start(self.cluster.n_workers)
         self._lock = threading.Lock()

@@ -90,7 +90,6 @@ class StudyDriver:
         input_keys: Optional[Sequence[Any]] = None,
         store_dir: Optional[str] = None,
         backend: Any = None,
-        hierarchy: Any = None,
         evaluate_delta: Optional[
             Callable[
                 [Sequence[ParamSet]],
@@ -128,10 +127,6 @@ class StudyDriver:
         # ProcessRpcBackend whose build() produces this study's workflow
         # and inputs in each worker process (DESIGN.md §13).
         self.backend = backend
-        # Scheduler topology spec for the session (DESIGN.md §15):
-        # None/"flat" for the single-pump Manager, int/"auto"/"fanout=N,..."
-        # for hierarchical sub-manager pumps.
-        self.hierarchy = hierarchy
         # Optional out-of-process evaluation hook (the fleet runner): given
         # the round's delta, returns (ParamSet -> objective, counter stats).
         # The hook owns planning/execution/state-merge; the driver keeps the
@@ -162,7 +157,6 @@ class StudyDriver:
                 heartbeat_timeout=self.cluster.heartbeat_timeout,
                 straggler_factor=self.cluster.straggler_factor,
                 enable_backup_tasks=self.cluster.enable_backup_tasks,
-                hierarchy=self.hierarchy,
             )
             st.manager.start(self.cluster.n_workers)
         return st.manager

@@ -2,8 +2,8 @@
 
 ``Manager.cancel`` must deliver exactly-once semantics under every race
 the service can produce — cancel while queued, cancel mid-lease, cancel
-while the key sits in a delegated sub-queue (the steal surface),
-double-cancel, cancel-then-resubmit — and behave identically on the
+of a backlog queued behind a busy pool, double-cancel,
+cancel-then-resubmit — and behave identically on the
 thread, process and socket backends. Each revoked key's callback fires
 exactly once with :class:`TaskCancelled`; a poisoned lease's eventual
 completion is dropped (never a second callback, never a resurrected
@@ -167,16 +167,15 @@ def test_cancel_race_matrix(tmp_path, backend):
 
 @pytest.mark.parametrize("backend", ["thread", "process"])
 def test_cancel_in_delegated_subqueue(tmp_path, backend):
-    """Cancel reaches work already distributed to a hierarchical
-    sub-pump's local queue (the steal surface): queued shards are purged
-    from the sub-queues, not just the global queue, and the freed workers
-    go on to complete unrelated work."""
+    """Cancel reaches a backlog queued behind a fully busy pool: the
+    revoked keys are purged from the queue before any worker frees up,
+    each settles exactly once as cancelled, and the freed workers go on
+    to complete the surviving work."""
     rec = _Recorder()
     mgr = _mk_manager(
         backend,
         tmp_path,
         n_workers=4,
-        hierarchy=2,
         enable_backup_tasks=False,
     )
     try:
@@ -192,19 +191,19 @@ def test_cancel_in_delegated_subqueue(tmp_path, backend):
         while sum(mgr.dispatch_counts.values()) < 4:
             assert time.monotonic() < deadline
             time.sleep(0.01)
-        # Backlog lands in the sub-pumps' local queues behind the nappers.
+        # The backlog queues behind the four nappers.
         for i in range(12):
             mgr.submit(
                 WorkItem(
                     key=f"s{i}",
                     spec=("call", _double, (i,), {}),
                     callback=rec.cb,
-                    path=("in", i % 4),
                 )
             )
         victims = [f"s{i}" for i in range(0, 12, 2)]
         cancelled = mgr.cancel(victims)
         assert sorted(cancelled) == sorted(victims)
+        assert mgr.scheduler_stats()["cancelled"] == len(victims)
         mgr.drain()
         for i in range(12):
             key = f"s{i}"

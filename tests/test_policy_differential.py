@@ -18,11 +18,23 @@ import random
 
 import pytest
 
-from repro.engine import ClusterSpec, execute_plan, execute_study, plan_study
-from repro.engine.types import POLICIES
+from repro.engine import (
+    ClusterSpec,
+    MemoryBudget,
+    ResultCache,
+    execute_plan,
+    execute_study,
+    plan_study,
+)
+from repro.engine.types import CACHING_POLICIES, POLICIES
 from repro.runtime.manager import Manager
 
-from study_gen import naive_outputs, random_param_sets, random_workflow
+from study_gen import (
+    naive_outputs,
+    random_param_sets,
+    random_workflow,
+    workflow_from_layout,
+)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -75,7 +87,7 @@ def _check_case(wf, sets, inputs, plan_kwargs, workers=(1, 3)):
             ), (pol, w)
 
 
-@pytest.mark.parametrize("seed", range(10))
+@pytest.mark.parametrize("seed", range(16))
 def test_differential_policy_matrix(seed):
     wf, sets, inputs, plan_kwargs = _random_case(9000 + seed)
     _check_case(wf, sets, inputs, plan_kwargs)
@@ -123,6 +135,76 @@ def test_cross_input_cache_isolation():
     for i, x in enumerate(inputs):
         assert stream.outputs[i] == naive_outputs(wf, sets, x), i
     assert stream.outputs[0] != stream.outputs[1]
+
+
+# Two stages of two tasks each, like the pathology pipeline's trie: a
+# parameter-free head, then one parameter per task.
+SESSION_LAYOUT = [
+    [("s0t0", (), 1.0, 64), ("s0t1", ("a",), 1.0, 64)],
+    [("s1t0", ("b",), 2.0, 64), ("s1t1", ("c",), 1.0, 64)],
+]
+
+
+def _ps(a, b, c):
+    return (("a", a), ("b", b), ("c", c))
+
+
+# One-at-a-time moves, as MOAT trajectories make them. Group 1 overlaps
+# group 0's sets; group 2 repeats group 0's (a, b) prefixes with new c.
+SESSION_GROUPS = [
+    [_ps(0, 0, 0), _ps(1, 0, 0), _ps(1, 1, 0), _ps(1, 1, 1)],
+    [_ps(1, 1, 1), _ps(2, 1, 1), _ps(2, 2, 1), _ps(2, 2, 2)],
+    [_ps(0, 0, 2), _ps(1, 0, 2), _ps(1, 1, 2), _ps(1, 1, 0)],
+]
+
+
+@pytest.mark.parametrize("n_workers", [1, 2])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_benchmark_session_shape(policy, n_workers):
+    """The shape the on-chip benchmark drives: one started Manager built
+    from the ClusterSpec, one ResultCache, and successive execute_study
+    calls over overlapping groups, each under its own key prefix. Every
+    call matches the oracle and covers its plan, the whole series starts
+    one session, and a caching policy serves the repeated prefixes of
+    the third group from the cache."""
+    wf = workflow_from_layout(SESSION_LAYOUT)
+    inputs, keys = [5, 13], ["tile0", "tile1"]
+    memory = MemoryBudget()
+    cluster = ClusterSpec(n_workers=n_workers)
+    cache = ResultCache(memory.effective_cache_bytes)
+    before = Manager.sessions_started
+    mgr = Manager(
+        max_attempts=cluster.max_attempts,
+        heartbeat_timeout=cluster.heartbeat_timeout,
+        straggler_factor=cluster.straggler_factor,
+        enable_backup_tasks=cluster.enable_backup_tasks,
+    )
+    mgr.start(cluster.n_workers)
+    try:
+        hits = []
+        for g, sets in enumerate(SESSION_GROUPS):
+            plan = plan_study(
+                wf, sets, memory=memory, cluster=cluster, policy=policy
+            )
+            res = execute_study(
+                plan, inputs, cache=cache, manager=mgr, input_keys=keys,
+                key_prefix=f"g{g}:",
+            )
+            assert res.manager_sessions == 0
+            for i, x in enumerate(inputs):
+                assert res.outputs[i] == naive_outputs(wf, sets, x), (g, i)
+            assert (
+                res.tasks_executed + res.cache_hits
+                == plan.tasks_executed * len(inputs)
+            ), g
+            hits.append(res.cache_hits)
+    finally:
+        mgr.close()
+    assert Manager.sessions_started - before == 1
+    if policy in CACHING_POLICIES:
+        assert hits[2] > 0, hits
+    else:
+        assert hits == [0, 0, 0]
 
 
 if HAVE_HYPOTHESIS:

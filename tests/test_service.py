@@ -554,21 +554,3 @@ def test_idle_pool_parks_and_stats_report_active_wall():
         assert parked_after >= stats["pump_parked_seconds"] - 1e-6
     finally:
         mgr.close()
-
-
-def test_idle_pool_parks_hierarchical_subpumps():
-    mgr = Manager(hierarchy=2)
-    mgr.start(4)
-    try:
-        for i in range(8):
-            mgr.submit(WorkItem(key=f"k{i}", fn=lambda i=i: i * 3))
-        mgr.drain()
-        time.sleep(0.35)
-        stats = mgr.scheduler_stats()
-        assert stats["mode"] == "hierarchical"
-        assert len(stats["sub_parked_seconds"]) == 2
-        assert all(p >= 0.0 for p in stats["sub_parked_seconds"])
-        assert sum(stats["sub_parked_seconds"]) > 0.2
-        assert mgr.results() == {f"k{i}": i * 3 for i in range(8)}
-    finally:
-        mgr.close()

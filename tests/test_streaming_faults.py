@@ -324,17 +324,13 @@ class TestPersistentManagerSessions:
 
 
 # ---------------------------------------------------------------------------
-# Work stealing under fire (ISSUE 7): steal storms + expired leases +
-# killed workers must preserve exactly-once settlement and callbacks
+# Storms: expired leases + aggressive backups + transient failures + killed
+# workers must preserve exactly-once settlement and callbacks. Any
+# double-lease, lost item, or double-settlement shows up as a wrong count.
 # ---------------------------------------------------------------------------
 
-# ``block=1, steal_min=1`` delegates one item at a time and lets every idle
-# pump raid every peer — the maximum-contention "steal storm" topology. Any
-# double-lease, lost item, or double-settlement shows up as a wrong count.
-STORM = "fanout={f},block=1,steal_min=1"
 
-
-def _hier_hang_until_killed(marker_dir):
+def _hang_until_killed(marker_dir):
     """Spawn-picklable: first execution in the fleet records its pid and
     hangs for the test to SIGKILL; retries return fast."""
     marker = pathlib.Path(marker_dir) / "pid"
@@ -349,17 +345,16 @@ def _hier_hang_until_killed(marker_dir):
     return "fast"
 
 
-def _hier_quick(tag):
+def _quick(tag):
     time.sleep(0.01)
     return f"q-{tag}"
 
 
 def test_steal_storm_with_expired_leases_exactly_once():
-    """Manager-level storm: 40 keys over 4 sub-pumps with one-item blocks,
-    aggressive backups (straggler_factor 0.5), one worker that goes dead
-    past the heartbeat deadline mid-lease, and transient failures. Every
-    key must settle exactly once — one callback, one result — and the
-    storm must actually steal (the topology guarantees imbalance)."""
+    """Manager-level storm: 40 keys over 4 workers, aggressive backups
+    (straggler_factor 0.5), one worker that goes dead past the heartbeat
+    deadline mid-lease, and transient failures. Every key must settle
+    exactly once — one callback, one result."""
     counts = {}
     lock = threading.Lock()
 
@@ -389,7 +384,6 @@ def test_steal_storm_with_expired_leases_exactly_once():
         heartbeat_timeout=0.05,
         straggler_factor=0.5,
         max_attempts=6,
-        hierarchy=STORM.format(f=4),
     )
     mgr.submit(WorkItem(key="dead", fn=dead_then_alive, callback=cb))
     mgr.submit(WorkItem(key="flaky", fn=flaky, callback=cb))
@@ -402,7 +396,6 @@ def test_steal_storm_with_expired_leases_exactly_once():
             )
         )
     out = mgr.run(4, expected=40)
-    stats = mgr.scheduler_stats()
     assert len(out) == 40
     assert out["dead"] in ("alive", "zombie")
     assert out["flaky"] == "ok"
@@ -411,16 +404,15 @@ def test_steal_storm_with_expired_leases_exactly_once():
         k: c for k, c in counts.items() if c != 1
     }
     assert set(counts) == set(out)
-    assert stats["mode"] == "hierarchical" and stats["fanout"] == 4
     assert mgr.heartbeat_expiries >= 1
     assert mgr.retries >= 3  # 2 injected faults + the expired lease
 
 
-def _check_streaming_storm(seed, fanout, failures, straggle):
-    """The storm property: streaming under a steal storm + transient
-    failures + an optional injected straggler (backup clones racing
-    originals) leaves outputs bit-identical to the fault-free oracle with
-    the exactly-once accounting identity intact."""
+def _check_streaming_storm(seed, n_workers, failures, straggle):
+    """The storm property: streaming over ``n_workers`` workers under
+    transient failures + an optional injected straggler (backup clones
+    racing originals) leaves outputs bit-identical to the fault-free oracle
+    with the exactly-once accounting identity intact."""
     inj = Injector()
     rng = random.Random(seed)
     wf, clean_wf, names, cards = instrumented_workflow(rng, inj)
@@ -438,9 +430,8 @@ def _check_streaming_storm(seed, fanout, failures, straggle):
             plan,
             inputs,
             cluster=ClusterSpec(
-                n_workers=4, max_attempts=8, straggler_factor=1.5
+                n_workers=n_workers, max_attempts=8, straggler_factor=1.5
             ),
-            hierarchy=STORM.format(f=fanout),
         )
     finally:
         inj.active = False
@@ -450,19 +441,19 @@ def _check_streaming_storm(seed, fanout, failures, straggle):
         stream.tasks_executed + stream.cache_hits
         == plan.tasks_executed * len(inputs)
     )
-    assert stream.scheduler["fanout"] == fanout
+    assert stream.manager_sessions == 1
 
 
-@pytest.mark.parametrize("seed,fanout,failures,straggle", [
+@pytest.mark.parametrize("seed,n_workers,failures,straggle", [
     (601, 2, 0, False),
     (602, 3, 2, False),
     (603, 4, 3, True),
     (604, 4, 1, True),
 ])
-def test_streaming_storm_bit_identical(seed, fanout, failures, straggle):
+def test_streaming_storm_bit_identical(seed, n_workers, failures, straggle):
     """Seeded instances of the storm property (always run; the hypothesis
     layer below explores the same contract when hypothesis is installed)."""
-    _check_streaming_storm(seed, fanout, failures, straggle)
+    _check_streaming_storm(seed, n_workers, failures, straggle)
 
 
 if HAVE_HYPOTHESIS:
@@ -470,22 +461,21 @@ if HAVE_HYPOTHESIS:
     class TestHypothesisStealStorm:
         @given(
             seed=st.integers(min_value=0, max_value=2**20),
-            fanout=st.sampled_from([2, 3, 4]),
+            n_workers=st.sampled_from([2, 3, 4]),
             failures=st.integers(min_value=0, max_value=3),
             straggle=st.booleans(),
         )
         @settings(max_examples=10, deadline=None)
         def test_streaming_storm_bit_identical(
-            self, seed, fanout, failures, straggle
+            self, seed, n_workers, failures, straggle
         ):
-            _check_streaming_storm(seed, fanout, failures, straggle)
+            _check_streaming_storm(seed, n_workers, failures, straggle)
 
 
 def test_sigkilled_worker_under_hierarchy_settles_exactly_once(tmp_path):
-    """fanout=2 over RPC worker processes, one worker SIGKILLed mid-lease:
-    the leader's heartbeat expiry re-enqueues the lease, a sub-pump whose
-    shard lost its only worker goes idle, and the surviving shard (via
-    redistribution/stealing) completes everything — every key exactly once."""
+    """Two RPC worker processes, one SIGKILLed mid-lease: the pump sees
+    the worker dead and re-enqueues its lease at once, and the surviving
+    worker completes everything — every key exactly once."""
     marker_dir = tmp_path / "marker"
     marker_dir.mkdir()
     counts = {}
@@ -501,14 +491,13 @@ def test_sigkilled_worker_under_hierarchy_settles_exactly_once(tmp_path):
         ),
         enable_backup_tasks=False,
         max_attempts=3,
-        hierarchy=STORM.format(f=2),
     )
     mgr.start(2)
     try:
         mgr.submit(
             WorkItem(
                 key="victim",
-                spec=("call", _hier_hang_until_killed, (str(marker_dir),), {}),
+                spec=("call", _hang_until_killed, (str(marker_dir),), {}),
                 callback=cb,
             )
         )
@@ -516,7 +505,7 @@ def test_sigkilled_worker_under_hierarchy_settles_exactly_once(tmp_path):
             mgr.submit(
                 WorkItem(
                     key=f"pad{i}",
-                    spec=("call", _hier_quick, (i,), {}),
+                    spec=("call", _quick, (i,), {}),
                     callback=cb,
                 )
             )
@@ -534,7 +523,6 @@ def test_sigkilled_worker_under_hierarchy_settles_exactly_once(tmp_path):
         assert all(c == 1 for c in counts.values()), counts
         assert set(counts) == set(out)
         assert mgr.heartbeat_expiries >= 1
-        assert mgr.scheduler_stats()["mode"] == "hierarchical"
     finally:
         mgr.close()
 
