@@ -5,11 +5,11 @@ nuclei through a chain of threshold / morphological operators, and compares
 each run's mask with the default-parameter mask (Dice). Every operator below
 is a pure, jittable function on ``float32``/``bool`` arrays. On a TPU,
 fill-holes runs the bit-packed Pallas kernel of ``repro.kernels.fill_holes``
-(where the tile's packed planes fit its VMEM budget), and component labels
-and the watershed flood run the row-blocked Pallas kernel of
-``repro.kernels.label``; off a TPU each runs its XLA loop. The reconstruction
-runs as an XLA loop on every backend; the float32 reconstruction kernel of
-``repro.kernels.morph_recon`` runs only when asked for by name.
+(where the tile's packed planes fit its VMEM budget), and the propagation
+loops run the row-blocked Pallas kernel of ``repro.kernels.label``: the
+float32 reconstruction (``repro.kernels.morph_recon``), component labels
+and the watershed flood settle in VMEM row blocks. Off a TPU each runs its
+XLA loop.
 
 Connectivity parameters (FH / RC / WConn in Table I) are 4 or 8 and must be
 *static* under jit (they select the structuring element).
@@ -71,16 +71,14 @@ def rbc_mask(rgb: jax.Array, t1: jax.Array, t2: jax.Array) -> jax.Array:
     return (r / g > t1) & (r / bl > t2)
 
 
-def morph_reconstruct(
-    marker: jax.Array, mask: jax.Array, conn: int = 8, *, use_kernel: bool = False
-) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("conn",))
+def morph_reconstruct(marker: jax.Array, mask: jax.Array, conn: int = 8) -> jax.Array:
     """Grayscale morphological reconstruction by dilation: iterate
-    ``marker ← min(dilate(marker), mask)`` to fixpoint. The pure-XLA loop
-    on every backend; ``use_kernel=True`` asks for the Pallas tile kernel,
-    which needs a TPU."""
+    ``marker ← min(dilate(marker), mask)`` to fixpoint. The row-blocked
+    Pallas kernel on a TPU, the XLA loop elsewhere (``kernels.ops``)."""
     from repro.kernels import ops as kops
 
-    return kops.morph_reconstruct(marker, mask, conn=conn, use_kernel=use_kernel)
+    return kops.morph_reconstruct(marker, mask, conn=conn)
 
 
 @functools.partial(jax.jit, static_argnames=("conn",))

@@ -122,7 +122,7 @@ def _t_rbc(state, T1, T2):
 def _t_recon(state, G1, RC):
     gray = state["gray"]
     marker = jnp.maximum(gray - jnp.float32(G1), 0.0)
-    recon = ops.morph_reconstruct(marker, gray, conn=int(RC), use_kernel=False)
+    recon = ops.morph_reconstruct(marker, gray, conn=int(RC))
     return {"gray": gray, "residual": gray - recon}
 
 

@@ -1,9 +1,12 @@
-"""Pallas TPU kernel for the int32 min-propagation fixpoints of the
-segmentation: connected-component labels and the watershed flood.
+"""Pallas TPU kernel for the propagation fixpoints of the segmentation:
+connected-component labels and the watershed flood (int32, here), and the
+float32 reconstruction by dilation (its chunk rule and exactness argument
+are in ``morph_recon``).
 
-The XLA loops (``ref.label_components_ref``, ``ref.flood_ref``) take one
-Jacobi step of the whole plane per iteration: one HBM round trip of a 4-byte
-plane per pixel of the largest component's geodesic diameter. Here the plane
+The XLA loops (``ref.label_components_ref``, ``ref.flood_ref``,
+``ref.morph_reconstruct_ref``) take one Jacobi step of the whole plane per
+iteration: one HBM round trip of each 4-byte plane per pixel that a value
+has to travel. Here the plane
 is cut into row blocks that fit the kernel's VMEM budget. A launch (a pass)
 visits the blocks in turn: it copies a block and one sublane tile of halo
 rows above and below into VMEM, relaxes the block there until nothing in it
@@ -16,16 +19,18 @@ changes nothing: every block is then stable against its neighbours' final
 rows, so the whole plane is at the fixpoint.
 
 Inside a block, a sweep visits 8-row chunks top to bottom and rewrites each
-in place from its neighbours (left and right by lane rolls onto a padding
-column, up and down by sublane rolls that take the chunk above's last row
-and the chunk below's first row). A chunk is recomputed only if it or a
-chunk next to it changed since its last visit; the block is settled after a
-sweep that changes nothing.
+in place by the caller's chunk rule from its neighbours (left and right by
+lane rolls onto a padding column, up and down by sublane rolls that take
+the chunk above's last row and the chunk below's first row). A chunk is
+recomputed only if it or a chunk next to it changed since its last visit;
+the block is settled after a sweep that changes nothing. The planes share
+one 4-byte element type; the rule may read planes it never writes (the
+reconstruction's mask), and only the planes it writes are copied back.
 
-Exactness. Both loops compute the unique fixpoint of a monotone relaxation,
-and any schedule of such relaxations that stops only when no pixel can be
-lowered reaches that fixpoint, so the order of blocks, chunks and sweeps
-does not change the result.
+Exactness. The two int32 loops compute the unique fixpoint of a monotone
+relaxation, and any schedule of such relaxations that stops only when no
+pixel can be lowered reaches that fixpoint, so the order of blocks, chunks
+and sweeps does not change the result.
 
 - Labels: each mask pixel takes the minimum flat index of its component.
   A pixel is lowered only to the label of a neighbour in the mask, so every
@@ -57,7 +62,7 @@ at least one column so that a lane roll's wrap lands on padding.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +85,7 @@ def _round_up(n: int, m: int) -> int:
 
 def layout(h: int, w: int, planes: int, block_rows: Optional[int] = None) -> Tuple[int, int, int]:
     """``(rows, blocks, wp)``: the rows of a block, the number of blocks and
-    the padded width for an ``h x w`` tile relaxed in ``planes`` int32
+    the padded width for an ``h x w`` tile relaxed in ``planes`` 4-byte
     planes. The block's row count follows from the width, so that the
     blocks' buffers with their halo rows fit ``VMEM_BUDGET``; ``block_rows``
     caps it (tests take a few rows a block to cross many blocks)."""
@@ -97,9 +102,27 @@ def _lexmin(a, b):
     return jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1])
 
 
-def _labels_chunk(up, cur, down, left_right, conn):
-    """New labels of one chunk, and the mask of pixels that changed."""
-    (cur,), (up,), (down,) = cur, up, down
+def stencil(bufs, r, row):
+    """The 8-row chunk at row ``r`` of each buffer, as ``(up, cur, down)``:
+    each pixel's upper neighbour, itself and its lower neighbour, the chunk
+    above's last row and the chunk below's first row included."""
+    above = [b[pl.ds(pl.multiple_of(r - SUBLANES, SUBLANES), SUBLANES), :] for b in bufs]
+    cur = [b[pl.ds(r, SUBLANES), :] for b in bufs]
+    below = [b[pl.ds(pl.multiple_of(r + SUBLANES, SUBLANES), SUBLANES), :] for b in bufs]
+    up = tuple(
+        jnp.where(row == 0, pltpu.roll(a, 1, 0), pltpu.roll(c, 1, 0))
+        for a, c in zip(above, cur)
+    )
+    down = tuple(
+        jnp.where(row == SUBLANES - 1, pltpu.roll(b, SUBLANES - 1, 0), pltpu.roll(c, SUBLANES - 1, 0))
+        for b, c in zip(below, cur)
+    )
+    return up, tuple(cur), down
+
+
+def _labels_chunk(bufs, r, row, left_right, conn):
+    """New labels of the chunk at row ``r``, and the pixels that changed."""
+    (up,), (cur,), (down,) = stencil(bufs, r, row)
     if conn == 8:  # the 3x3 minimum, separably: rows first, then columns
         v = jnp.minimum(jnp.minimum(up, cur), down)
         new = jnp.minimum(v, jnp.minimum(*left_right(v)))
@@ -109,8 +132,10 @@ def _labels_chunk(up, cur, down, left_right, conn):
     return (new,), new != cur
 
 
-def _flood_chunk(up, cur, down, left_right, conn):
-    """New (distance, label) pairs of one chunk, and the pixels that changed."""
+def _flood_chunk(bufs, r, row, left_right, conn):
+    """New (distance, label) pairs of the chunk at row ``r``, and the pixels
+    that changed."""
+    up, cur, down = stencil(bufs, r, row)
     if conn == 8:  # the 3x3 lexicographic minimum (the pixel itself loses at d + 1)
         v = _lexmin(_lexmin(up, cur), down)
         (ld, rd), (ll, rl) = left_right(v[0]), left_right(v[1])
@@ -123,14 +148,16 @@ def _flood_chunk(up, cur, down, left_right, conn):
     return (jnp.where(better, nd, cur[0]), jnp.where(better, nl, cur[1])), better
 
 
-def _relax_kernel(rev_ref, *refs, planes: int, rows: int, blocks: int, conn: int):
-    refs = refs[planes:]  # the inputs alias the outputs: read and write through the latter
-    planes_ref, (changed_ref,) = refs[:planes], refs[planes : planes + 1]
-    bufs = refs[planes + 1 : 2 * planes + 1]
-    sem, last = refs[2 * planes + 1 :]  # last[k + 1]: the sweep in which chunk k last changed
+def _relax_kernel(
+    rev_ref, *refs, chunk, planes: int, written: int, rows: int, blocks: int, conn: int
+):
+    ins, refs = refs[:planes], refs[planes:]
+    # a written plane's input aliases its output: read and write through the latter
+    planes_ref, (changed_ref,) = refs[:written] + ins[written:], refs[written : written + 1]
+    bufs = refs[written + 1 : written + planes + 1]
+    sem, last = refs[written + planes + 1 :]  # last[k + 1]: the sweep in which chunk k last changed
     wp = bufs[0].shape[1]
     chunks = rows // SUBLANES
-    chunk_fn = _labels_chunk if planes == 1 else _flood_chunk
     row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, wp), 0)
 
     def left_right(x):
@@ -138,18 +165,7 @@ def _relax_kernel(rev_ref, *refs, planes: int, rows: int, blocks: int, conn: int
 
     def update(k):
         r = pl.multiple_of(HALO + k * SUBLANES, SUBLANES)
-        above = [b[pl.ds(pl.multiple_of(r - SUBLANES, SUBLANES), SUBLANES), :] for b in bufs]
-        cur = [b[pl.ds(r, SUBLANES), :] for b in bufs]
-        below = [b[pl.ds(pl.multiple_of(r + SUBLANES, SUBLANES), SUBLANES), :] for b in bufs]
-        up = tuple(
-            jnp.where(row == 0, pltpu.roll(a, 1, 0), pltpu.roll(c, 1, 0))
-            for a, c in zip(above, cur)
-        )
-        down = tuple(
-            jnp.where(row == SUBLANES - 1, pltpu.roll(b, SUBLANES - 1, 0), pltpu.roll(c, SUBLANES - 1, 0))
-            for b, c in zip(below, cur)
-        )
-        new, moved = chunk_fn(up, tuple(cur), down, left_right, conn)
+        new, moved = chunk(bufs, r, row, left_right, conn)
         for b, n in zip(bufs, new):
             b[pl.ds(r, SUBLANES), :] = n
         return jnp.max(jnp.where(moved, 1.0, 0.0)) > 0.0
@@ -204,7 +220,7 @@ def _relax_kernel(rev_ref, *refs, planes: int, rows: int, blocks: int, conn: int
                 pltpu.make_async_copy(
                     buf.at[pl.ds(HALO, rows)], p.at[pl.ds(r0 + HALO, rows)], sem.at[j]
                 )
-                for j, (p, buf) in enumerate(zip(planes_ref, bufs))
+                for j, (p, buf) in enumerate(zip(planes_ref[:written], bufs))
             ]
             for c in back:
                 c.start()
@@ -216,45 +232,83 @@ def _relax_kernel(rev_ref, *refs, planes: int, rows: int, blocks: int, conn: int
     changed_ref[0, 0] = jax.lax.fori_loop(0, blocks, block, False).astype(jnp.int32)
 
 
-def _relax(
-    planes: Sequence[jax.Array],
-    fills: Sequence[int],
+def launch(
+    chunk: Callable,
+    shape: Tuple[int, int],
+    dtype,
     *,
+    planes: int,
+    written: int,
+    rows: int,
     conn: int,
     interpret: bool,
-    block_rows: Optional[int],
 ):
-    """Relax ``planes`` (int32, ``h x w``) to the fixpoint; returns the
-    planes and the number of passes (launches) it took."""
-    if conn not in (4, 8):
-        raise ValueError(f"connectivity must be 4 or 8, got {conn}")
-    n = len(planes)
-    h, w = planes[0].shape
-    rows, blocks, wp = layout(h, w, n, block_rows)
-    pad = ((HALO, HALO + blocks * rows - h), (0, wp - w))
-    padded = tuple(jnp.pad(p, pad, constant_values=f) for p, f in zip(planes, fills))
-    shape = jax.ShapeDtypeStruct(padded[0].shape, jnp.int32)
-    call = pl.pallas_call(
-        functools.partial(_relax_kernel, planes=n, rows=rows, blocks=blocks, conn=conn),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [pl.BlockSpec(memory_space=pl.ANY)] * n,
-        out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * n + (pl.BlockSpec(memory_space=pltpu.SMEM),),
-        out_shape=(shape,) * n + (jax.ShapeDtypeStruct((1, 1), jnp.int32),),
-        scratch_shapes=[pltpu.VMEM((rows + 2 * HALO, wp), jnp.int32)] * n
-        + [pltpu.SemaphoreType.DMA((n,)), pltpu.SMEM((rows // SUBLANES + 2,), jnp.int32)],
-        input_output_aliases={1 + j: j for j in range(n)},
+    """One pass over padded planes of ``shape`` (blocks of ``rows`` rows,
+    ``HALO`` rows above the first and below the last):
+    ``call(rev, *planes) -> (*written planes, changed)``, each ``(1, 1)``
+    int32 scalar in SMEM. ``chunk`` is the rule that rewrites one 8-row
+    chunk (``chunk(bufs, r, row, left_right, conn) -> (new planes,
+    changed)``); it rewrites the first ``written`` of the ``planes`` planes
+    and only reads the rest."""
+    blocks, wp = (shape[0] - 2 * HALO) // rows, shape[1]
+    out = jax.ShapeDtypeStruct(shape, dtype)
+    return pl.pallas_call(
+        functools.partial(
+            _relax_kernel,
+            chunk=chunk, planes=planes, written=written, rows=rows, blocks=blocks, conn=conn,
+        ),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * planes,
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * written
+        + (pl.BlockSpec(memory_space=pltpu.SMEM),),
+        out_shape=(out,) * written + (jax.ShapeDtypeStruct((1, 1), jnp.int32),),
+        scratch_shapes=[pltpu.VMEM((rows + 2 * HALO, wp), dtype)] * planes
+        + [pltpu.SemaphoreType.DMA((planes,)), pltpu.SMEM((rows // SUBLANES + 2,), jnp.int32)],
+        input_output_aliases={1 + j: j for j in range(written)},
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=n * (rows + 2 * HALO) * wp * 4 + _VMEM_HEADROOM
+            vmem_limit_bytes=planes * (rows + 2 * HALO) * wp * 4 + _VMEM_HEADROOM
         ),
         interpret=interpret,
     )
 
+
+def relax(
+    chunk: Callable,
+    planes: Sequence[jax.Array],
+    fills: Sequence[Union[int, float]],
+    *,
+    conn: int,
+    interpret: bool,
+    block_rows: Optional[int],
+    written: Optional[int] = None,
+):
+    """Relax ``planes`` (``h x w``, one 4-byte dtype) to the fixpoint of
+    ``chunk`` (see :func:`launch`), padding each plane with its ``fills``.
+    The first ``written`` planes (all by default) are the rule's to rewrite,
+    the rest it only reads. Returns the written planes and the number of
+    passes (launches) it took."""
+    if conn not in (4, 8):
+        raise ValueError(f"connectivity must be 4 or 8, got {conn}")
+    n = len(planes)
+    written = n if written is None else written
+    dtype = planes[0].dtype
+    h, w = planes[0].shape
+    rows, blocks, wp = layout(h, w, n, block_rows)
+    pad = ((HALO, HALO + blocks * rows - h), (0, wp - w))
+    padded = tuple(jnp.pad(p, pad, constant_values=f) for p, f in zip(planes, fills))
+    call = launch(
+        chunk, padded[0].shape, dtype,
+        planes=n, written=written, rows=rows, conn=conn, interpret=interpret,
+    )
+    fixed = padded[written:]
+
     def body(state):
         cur, k, _ = state
-        *cur, changed = call(jnp.full((1, 1), k % 2, jnp.int32), *cur)
+        *cur, changed = call(jnp.full((1, 1), k % 2, jnp.int32), *cur, *fixed)
         return tuple(cur), k + 1, changed[0, 0] != 0
 
     out, passes, _ = jax.lax.while_loop(
-        lambda s: s[2], body, (padded, jnp.int32(0), jnp.bool_(True))
+        lambda s: s[2], body, (padded[:written], jnp.int32(0), jnp.bool_(True))
     )
     return tuple(p[HALO : HALO + h, :w] for p in out), passes
 
@@ -277,8 +331,9 @@ def label_components_pallas(
     the number of launches the fixpoint took, an int32 scalar."""
     h, w = mask.shape
     idx = jnp.arange(h * w, dtype=jnp.int32).reshape(h, w)
-    (lab,), passes = _relax(
-        (jnp.where(mask, idx, BG),), (BG,), conn=conn, interpret=interpret, block_rows=block_rows
+    (lab,), passes = relax(
+        _labels_chunk, (jnp.where(mask, idx, BG),), (BG,),
+        conn=conn, interpret=interpret, block_rows=block_rows,
     )
     out = jnp.where(lab == BG, -1, lab)
     return (out, passes) if return_passes else out
@@ -302,7 +357,8 @@ def flood_pallas(
     h, w = seeds.shape
     big = h * w
     dist = jnp.where(seeds != big, 0, jnp.where(pre, UNREACHED, FAR)).astype(jnp.int32)
-    (_, lab), passes = _relax(
-        (dist, seeds), (FAR, big), conn=conn, interpret=interpret, block_rows=block_rows
+    (_, lab), passes = relax(
+        _flood_chunk, (dist, seeds), (FAR, big),
+        conn=conn, interpret=interpret, block_rows=block_rows,
     )
     return (lab, passes) if return_passes else lab

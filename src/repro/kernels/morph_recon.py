@@ -1,130 +1,87 @@
-"""Pallas TPU kernel for morphological reconstruction by dilation — the
-propagation hot-spot of the paper's segmentation stage (it also powers
-fill-holes and the watershed flooding).
+"""Pallas TPU kernel for seg2's grayscale reconstruction by dilation: the
+float32 plane settles in VMEM row blocks.
 
-TPU adaptation (DESIGN.md §2/§8): the CPU/GPU algorithms use irregular
-wavefront queues, which do not map to the MXU/VPU. Instead we tile the image
-into VMEM-resident blocks and run *many local sweeps per block per kernel
-launch* (raster + anti-raster, the classic two-pass SE decomposition), so the
-bulk of the propagation happens at VMEM bandwidth; a cheap global dilate-min
-step between launches carries wavefronts across tile boundaries, and an outer
-``while_loop`` iterates to the global fixpoint. Convergence is exact — the
-fixpoint test is on the full image.
+The XLA loop (``ref.morph_reconstruct_ref``) takes one Jacobi step
+``r <- min(dilate(r), mask)`` of the whole plane per iteration: one HBM
+round trip of two float32 planes per pixel that a value has to travel.
+Here the row-block driver of ``kernels.label`` runs it: a launch (a pass)
+copies each block of the reconstruction ``r`` and of ``mask``, with one
+sublane tile of halo rows, into VMEM, relaxes the block's 8-row chunks
+there until nothing in it changes (skipping chunks whose neighbourhood is
+settled) and writes ``r`` back; passes alternate direction and stop at the
+first that changes nothing. The chunk rule is
+``new = min(max over N[p] and p of r, mask_p)``, with ``N`` the 3x3
+neighbourhood for conn 8 (rows first, then columns, as the labels do) and
+the cross for conn 4; a pixel changed where ``new != r``. With two float32
+planes a 4096-wide tile takes 5 blocks of 824 rows.
 
-Blocks default to 256×256 fp32 (256 KiB/buffer; marker+mask+out ≈ 768 KiB of
-VMEM, well under the ~16 MiB/core budget), and both block dims are multiples
-of the 8×128 VPU tile.
+Exactness: the result equals the XLA loop's bit for bit, whatever the order
+of blocks, chunks and sweeps.
+
+- Both start from ``m0 = min(marker, mask)``. Each relaxation
+  ``r_p <- min(max_{q in N[p] and p} r_q, mask_p)`` is monotone: it never
+  lowers a pixel (``r_p <= mask_p`` holds throughout), and applied to a
+  state no higher than a fixpoint ``F >= m0`` it yields a state no higher
+  than ``F``. By induction every state lies between ``m0`` and the least
+  fixpoint above ``m0``.
+- A state in which no relaxation can raise a pixel is a fixpoint above
+  ``m0``, hence that least fixpoint; the XLA loop stops at the same one.
+- The rule takes only ``max`` and ``min`` of input values, no arithmetic,
+  so every value is one of the inputs' and the fixpoint is reached exactly.
+
+Padding rows, columns and the lane roll's wrap column hold a large negative
+finite fill in both planes: ``min`` with a padded mask keeps them there, and
+``max`` with them never raises a pixel inside.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_FILL = -3.0e38  # acts as -inf for propagation fills (plain float: kernels
-# must not capture traced constants)
+from repro.kernels.label import SUBLANES, relax, stencil
+
+_FILL = -3.0e38  # below every value a tile holds; a plain float, not a traced constant
 
 
-def _shift_block(x: jax.Array, dy: int, dx: int) -> jax.Array:
-    """Static-shape shift with -inf fill, using concat (TPU-lowerable)."""
-    h, w = x.shape
-    if dy == 1:
-        x = jnp.concatenate([jnp.full((1, w), _FILL, x.dtype), x[:-1]], axis=0)
-    elif dy == -1:
-        x = jnp.concatenate([x[1:], jnp.full((1, w), _FILL, x.dtype)], axis=0)
-    if dx == 1:
-        x = jnp.concatenate([jnp.full((h, 1), _FILL, x.dtype), x[:, :-1]], axis=1)
-    elif dx == -1:
-        x = jnp.concatenate([x[:, 1:], jnp.full((h, 1), _FILL, x.dtype)], axis=1)
-    return x
-
-
-def _neighbors(conn: int) -> Tuple[Tuple[int, int], ...]:
-    if conn == 4:
-        return ((1, 0), (-1, 0), (0, 1), (0, -1))
-    return ((1, 0), (-1, 0), (0, 1), (0, -1), (1, 1), (1, -1), (-1, 1), (-1, -1))
-
-
-def _recon_sweep_kernel(marker_ref, mask_ref, out_ref, *, conn: int, inner_iters: int):
-    """``inner_iters`` local dilate-min sweeps over one VMEM block."""
-    m = marker_ref[...]
-    mk = mask_ref[...]
-
-    def body(_, m):
-        d = m
-        for dy, dx in _neighbors(conn):
-            d = jnp.maximum(d, _shift_block(m, dy, dx))
-        return jnp.minimum(d, mk)
-
-    out_ref[...] = jax.lax.fori_loop(0, inner_iters, body, m)
+def _recon_chunk(bufs, r, row, left_right, conn):
+    """New reconstruction of the chunk at row ``r`` (``bufs``: ``r``, then
+    the mask, read only), and the pixels that rose."""
+    (up,), (cur,), (down,) = stencil(bufs[:1], r, row)
+    mask = bufs[1][pl.ds(r, SUBLANES), :]
+    if conn == 8:  # the 3x3 maximum, separably: rows first, then columns
+        v = jnp.maximum(jnp.maximum(up, cur), down)
+        new = jnp.maximum(v, jnp.maximum(*left_right(v)))
+    else:
+        new = jnp.maximum(jnp.maximum(up, down), jnp.maximum(cur, jnp.maximum(*left_right(cur))))
+    new = jnp.minimum(new, mask)
+    return (new,), new != cur
 
 
 @functools.partial(
-    jax.jit, static_argnames=("conn", "block", "inner_iters", "interpret")
-)
-def tile_sweep(
-    marker: jax.Array,
-    mask: jax.Array,
-    *,
-    conn: int = 8,
-    block: Tuple[int, int] = (256, 256),
-    inner_iters: int = 8,
-    interpret: bool = False,
-) -> jax.Array:
-    """One kernel launch: every block independently runs ``inner_iters``
-    local reconstruction sweeps. Pads to block multiples with -inf marker /
-    -inf mask so padding can never propagate into the image."""
-    h, w = marker.shape
-    bh = min(block[0], max(8, h))
-    bw = min(block[1], max(128, w)) if w >= 128 else w
-    hp = -(-h // bh) * bh
-    wp = -(-w // bw) * bw
-    mk = jnp.pad(marker.astype(jnp.float32), ((0, hp - h), (0, wp - w)), constant_values=float(_FILL))
-    ms = jnp.pad(mask.astype(jnp.float32), ((0, hp - h), (0, wp - w)), constant_values=float(_FILL))
-    out = pl.pallas_call(
-        functools.partial(_recon_sweep_kernel, conn=conn, inner_iters=inner_iters),
-        grid=(hp // bh, wp // bw),
-        in_specs=[
-            pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
-            pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((hp, wp), jnp.float32),
-        interpret=interpret,
-    )(mk, ms)
-    return out[:h, :w]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("conn", "block", "inner_iters", "interpret")
+    jax.jit, static_argnames=("conn", "interpret", "return_passes", "block_rows")
 )
 def morph_reconstruct_pallas(
     marker: jax.Array,
     mask: jax.Array,
     *,
     conn: int = 8,
-    block: Tuple[int, int] = (256, 256),
-    inner_iters: int = 8,
     interpret: bool = False,
-) -> jax.Array:
-    """Full reconstruction to the global fixpoint (kernel sweeps + cross-tile
-    exchange). Matches ``ref.morph_reconstruct_ref`` exactly."""
-    from repro.kernels import ref as kref
-
-    marker = jnp.minimum(marker.astype(jnp.float32), mask.astype(jnp.float32))
+    return_passes: bool = False,
+    block_rows: Optional[int] = None,
+):
+    """Reconstruction by dilation of ``marker`` under ``mask`` (float32),
+    to the global fixpoint; equals ``ref.morph_reconstruct_ref`` bit for
+    bit. With ``return_passes`` also the number of launches it took, an
+    int32 scalar."""
     mask = mask.astype(jnp.float32)
-
-    def body(state):
-        m, _ = state
-        m1 = tile_sweep(
-            m, mask, conn=conn, block=block, inner_iters=inner_iters, interpret=interpret
-        )
-        m2 = jnp.minimum(kref.dilate(m1, conn=conn), mask)  # cross-tile carry
-        return m2, jnp.any(m2 != m)
-
-    out, _ = jax.lax.while_loop(lambda s: s[1], body, (marker, jnp.bool_(True)))
-    return out
+    marker = jnp.minimum(marker.astype(jnp.float32), mask)
+    (out,), passes = relax(
+        _recon_chunk, (marker, mask), (_FILL, _FILL),
+        conn=conn, interpret=interpret, block_rows=block_rows, written=1,
+    )
+    return (out, passes) if return_passes else out

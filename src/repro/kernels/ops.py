@@ -1,19 +1,20 @@
 """Jitted dispatching wrappers for the Pallas kernels.
 
-With ``use_kernel=None`` a wrapper runs the Pallas kernel on a TPU backend
-and the pure-XLA reference path elsewhere. ``use_kernel=True`` asks for the
-kernel, which is an error off a TPU: the Pallas interpreter runs only where
-a caller of the kernel itself asks for it by name (``interpret=True``, as
-the kernel tests do). ``fill_holes``, ``label_components`` and ``flood``
-take no such option: ``fill_holes`` runs its kernel on a TPU wherever the
-tile's packed planes fit the kernel's VMEM budget, the other two run theirs
-on a TPU at every shape (their row blocks follow from the width), and each
-runs its XLA loop elsewhere.
+The segmentation's operators take no option: ``morph_reconstruct``,
+``label_components`` and ``flood`` run their row-blocked VMEM kernels
+(``kernels/morph_recon.py``, ``kernels/label.py``) on a TPU at every shape
+(their row blocks follow from the width), ``fill_holes`` runs its kernel on
+a TPU wherever the tile's packed planes fit the kernel's VMEM budget, and
+each runs its XLA loop elsewhere. The other wrappers take ``use_kernel``:
+with ``None`` they run the Pallas kernel on a TPU backend and the pure-XLA
+reference elsewhere; ``True`` asks for the kernel, which is an error off a
+TPU. The Pallas interpreter runs only where a caller of the kernel itself
+asks for it by name (``interpret=True``, as the kernel tests do).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 
@@ -34,26 +35,13 @@ def _want_kernel(use_kernel: Optional[bool], name: str) -> bool:
     return use_kernel
 
 
-def morph_reconstruct(
-    marker: jax.Array,
-    mask: jax.Array,
-    *,
-    conn: int = 8,
-    use_kernel: Optional[bool] = None,
-    block: Tuple[int, int] = (256, 256),
-    inner_iters: int = 8,
-) -> jax.Array:
-    """Morphological reconstruction by dilation (see kernels/morph_recon.py)."""
-    if _want_kernel(use_kernel, "morph_reconstruct"):
+def morph_reconstruct(marker: jax.Array, mask: jax.Array, *, conn: int = 8) -> jax.Array:
+    """Grayscale reconstruction by dilation (see kernels/morph_recon.py):
+    the row-blocked VMEM kernel on a TPU, else the XLA loop."""
+    if _want_kernel(None, "morph_reconstruct"):
         from repro.kernels.morph_recon import morph_reconstruct_pallas
 
-        return morph_reconstruct_pallas(
-            marker,
-            mask,
-            conn=conn,
-            block=block,
-            inner_iters=inner_iters,
-        )
+        return morph_reconstruct_pallas(marker, mask, conn=conn)
     return kref.morph_reconstruct_ref(marker, mask, conn=conn)
 
 

@@ -21,7 +21,7 @@ from repro.app import ops
 from repro.kernels import label as klabel
 from repro.kernels.fill_holes import fill_holes_pallas
 from repro.kernels.label import flood_pallas, label_components_pallas
-from repro.kernels.morph_recon import morph_reconstruct_pallas, tile_sweep
+from repro.kernels.morph_recon import morph_reconstruct_pallas
 from repro.kernels.ref import morph_reconstruct_ref
 
 SIZE = 4096
@@ -66,12 +66,12 @@ def _scalar(dtype, sharding):
 
 # name -> (jitted fn, static kwargs, argument builder, holds a Pallas kernel)
 CASES = {
-    "tile_sweep": (
-        tile_sweep, {"conn": 8, "interpret": False},
-        lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), True,
-    ),
     "morph_reconstruct_pallas": (
         morph_reconstruct_pallas, {"conn": 8, "interpret": False},
+        lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), True,
+    ),
+    "morph_reconstruct_pallas_conn4": (
+        morph_reconstruct_pallas, {"conn": 4, "interpret": False},
         lambda s: (_plane(jnp.float32, s), _plane(jnp.float32, s)), True,
     ),
     "morph_reconstruct_ref": (
@@ -160,5 +160,19 @@ def test_label_kernels_need_no_hbm_plane_beyond_the_relaxed_ones(conn, one_chip)
     assert mem.temp_size_in_bytes <= padded, mem.temp_size_in_bytes
     mem = flood_pallas.lower(ints, bools, conn=conn).compile().memory_analysis()
     assert mem.argument_size_in_bytes == 5 * SIZE * SIZE
+    assert mem.output_size_in_bytes == 4 * SIZE * SIZE
+    assert mem.temp_size_in_bytes <= 2 * padded, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("conn", [4, 8])
+def test_recon_kernel_needs_no_hbm_plane_beyond_the_relaxed_ones(conn, one_chip):
+    """The reconstruction settles in VMEM: besides the marker and mask in
+    and the result out, HBM holds the two padded float32 planes (the
+    reconstruction and the mask) and little else."""
+    rows, blocks, wp = klabel.layout(SIZE, SIZE, 2)
+    padded = (blocks * rows + 2 * klabel.HALO) * wp * 4  # one padded float32 plane
+    f32 = _plane(jnp.float32, one_chip)
+    mem = morph_reconstruct_pallas.lower(f32, f32, conn=conn).compile().memory_analysis()
+    assert mem.argument_size_in_bytes == 8 * SIZE * SIZE
     assert mem.output_size_in_bytes == 4 * SIZE * SIZE
     assert mem.temp_size_in_bytes <= 2 * padded, mem.temp_size_in_bytes

@@ -3,13 +3,15 @@
 * paths that spawn JAX child processes refuse to start on a TPU host,
   before anything is spawned (a chip belongs to one process);
 * a Pallas kernel asked for off a TPU is an error, never a silent switch
-  to the interpreter;
+  to the interpreter; the segmentation's operators take no such option and
+  choose their kernels by the backend alone;
 * the persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
   says, else to ``<checkout>/.jax_cache``;
 * ``chip_smoke.py`` fails, and prints no result, without a TPU or without
   the rest of the repository.
 """
 
+import inspect
 import multiprocessing
 import os
 import pathlib
@@ -86,6 +88,13 @@ def _ssm_case():
     return x, a, b, b
 
 
+def _ask_for_the_kernel(name, case):
+    if name == "morph_reconstruct":  # no option: the backend alone chooses
+        assert "use_kernel" not in inspect.signature(kops.morph_reconstruct).parameters
+        return kops.morph_reconstruct(*case())
+    return getattr(kops, name)(*case(), use_kernel=True)
+
+
 @pytest.mark.parametrize(
     "name,case",
     [
@@ -94,33 +103,59 @@ def _ssm_case():
         ("ssm_scan", _ssm_case),
     ],
 )
-def test_kernel_wrappers_refuse_the_kernel_off_tpu(name, case):
+def test_kernel_wrappers_refuse_the_kernel_off_tpu(name, case, monkeypatch):
     assert jax.default_backend() != "tpu"
+    if name == "morph_reconstruct":
+        # the wrapper has no way to ask for the kernel off a TPU: it never
+        # reaches it, and runs the XLA loop
+        from repro.kernels import morph_recon
+
+        monkeypatch.setattr(morph_recon, "morph_reconstruct_pallas", _never_called)
+        marker, mask = _recon_case()
+        np.testing.assert_array_equal(
+            np.asarray(_ask_for_the_kernel(name, case)),
+            np.asarray(morph_reconstruct_ref(marker, mask, conn=8)),
+        )
+        return
     with pytest.raises(RuntimeError, match="off a TPU"):
-        getattr(kops, name)(*case(), use_kernel=True)
+        _ask_for_the_kernel(name, case)
 
 
 def test_kernel_runs_off_tpu_only_when_the_interpreter_is_named():
     marker, mask = _recon_case()
     ref = morph_reconstruct_ref(marker, mask, conn=8)
-    got = morph_reconstruct_pallas(marker, mask, conn=8, block=(8, 8), interpret=True)
+    got = morph_reconstruct_pallas(marker, mask, conn=8, block_rows=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     # by default the kernel is compiled for the chip, never interpreted
     with pytest.raises(ValueError, match="interpret"):
-        morph_reconstruct_pallas(marker, mask, conn=8, block=(8, 8))
-    # a wrapper with no kernel asked for off a TPU: the XLA reference
+        morph_reconstruct_pallas(marker, mask, conn=8, block_rows=8)
+    # the wrapper off a TPU: the XLA reference
     np.testing.assert_array_equal(
         np.asarray(kops.morph_reconstruct(marker, mask)), np.asarray(ref)
     )
 
 
-def test_pipeline_morphology_is_the_same_xla_loop_on_a_tpu(fake_tpu):
-    # with the backend reported as a TPU, a bare call still takes the XLA
-    # reference (a kernel call would fail here: no TPU is attached)
-    marker, mask = _recon_case()
+def test_pipeline_morphology_is_the_same_xla_loop_on_a_tpu(fake_tpu, monkeypatch):
+    # with the backend reported as a TPU, seg2's reconstruction takes the
+    # row-block kernel (run here in the interpreter: no TPU is attached)
+    from repro.kernels import morph_recon
+
+    chosen = []
+
+    def spy(marker, mask, **kw):
+        chosen.append(kw)
+        return morph_reconstruct_pallas(marker, mask, interpret=True, **kw)
+
+    monkeypatch.setattr(morph_recon, "morph_reconstruct_pallas", spy)
+    rng = np.random.default_rng(1)
+    mask = jnp.asarray(rng.uniform(-20, 100, (24, 136)).astype(np.float32))  # a shape of its own
+    marker = jnp.maximum(mask - 30.0, 0.0)
     got = app_ops.morph_reconstruct(marker, mask, conn=4)
+    assert chosen == [{"conn": 4}]
     ref = morph_reconstruct_ref(marker, mask, conn=4)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    jaxpr = jax.make_jaxpr(lambda a, b: kops.morph_reconstruct(a, b, conn=8))(marker, mask)
+    assert "pallas_call" in str(jaxpr)
 
 
 def test_compile_cache_defaults_to_the_checkout(monkeypatch):
